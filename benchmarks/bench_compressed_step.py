@@ -28,6 +28,7 @@ from typing import Dict, List
 
 _CHILD = """
 import dataclasses, json, time
+from repro.launch.mesh import make_mesh
 import numpy as np, jax, jax.numpy as jnp
 from repro.configs import get_config
 from repro.configs.base import PGMConfig, TrainConfig
@@ -45,8 +46,8 @@ bundle = build_model(cfg)
 units = lm_units(make_lm_corpus(0, N_EX, SEQ, cfg.vocab_size,
                                 hard_fraction=0.4), unit_size=UNIT)
 base = TrainConfig(lr=0.5, optimizer="sgd", epochs=1, pgm=PGMConfig())
-gspmd_mesh = jax.make_mesh((2, 2), ("data", "model"))
-pod_mesh = jax.make_mesh((2, 2), ("data", "pod"))
+gspmd_mesh = make_mesh((2, 2), ("data", "model"))
+pod_mesh = make_mesh((2, 2), ("data", "pod"))
 
 variants = {
     "gspmd": (base, gspmd_mesh),
@@ -95,6 +96,9 @@ def bench_compressed_step() -> List[Dict]:
     root = os.path.join(os.path.dirname(__file__), "..")
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(root, "src")
+    # a forced-host-device CPU measurement: pinned to the CPU so the
+    # child never competes with its parent for an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     p = subprocess.run([sys.executable, "-c", _CHILD], capture_output=True,
                        text=True, env=env, timeout=900)

@@ -25,6 +25,7 @@ from typing import Dict, List
 
 _CHILD = """
 import json, time
+from repro.launch.mesh import make_mesh
 import numpy as np, jax, jax.numpy as jnp
 from repro.configs import get_config
 from repro.configs.base import PGMConfig, TrainConfig
@@ -43,7 +44,7 @@ units = lm_units(make_lm_corpus(0, N_EX, SEQ, cfg.vocab_size,
                                 hard_fraction=0.4), unit_size=UNIT)
 tc = TrainConfig(lr=0.5, optimizer="sgd", epochs=1, pgm=PGMConfig())
 opt_init, _ = make_update_for(tc)
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = make_mesh((2, 2), ("data", "model"))
 
 engines = {
     "scan": EpochEngine(bundle, tc, units, batch_units=BATCH_UNITS),
@@ -120,6 +121,9 @@ def bench_sharded_epoch() -> List[Dict]:
     root = os.path.join(os.path.dirname(__file__), "..")
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(root, "src")
+    # a forced-host-device CPU measurement: pinned to the CPU so the
+    # child never competes with its parent for an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     p = subprocess.run([sys.executable, "-c", _CHILD], capture_output=True,
                        text=True, env=env, timeout=900)

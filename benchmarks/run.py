@@ -18,8 +18,15 @@ import sys
 import time
 
 
-def main() -> None:
+def main() -> int:
+    """Run every phase; exit status 1 when any printed an ERROR row."""
     t_start = time.time()
+    errors = []
+
+    def error_row(name, e):
+        print(f"{name},0,ERROR={type(e).__name__}:{e}")
+        errors.append(name)
+
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
     from benchmarks.tables import ALL_TABLES
     from benchmarks.bench_kernels import bench_kernels
@@ -40,14 +47,14 @@ def main() -> None:
         print(f"check_static/full_repo,{dt*1e6:.1f},"
               f"findings={len(findings)};rules={len(all_rules())}")
     except Exception as e:
-        print(f"check_static,0,ERROR={type(e).__name__}:{e}")
+        error_row("check_static", e)
 
     for fn in ALL_TABLES:
         t0 = time.time()
         try:
             rows = fn()
-        except Exception as e:  # keep the harness green; report the failure
-            print(f"{fn.__name__},0,ERROR={type(e).__name__}:{e}")
+        except Exception as e:  # report the failure, run the other phases
+            error_row(fn.__name__, e)
             continue
         for r in rows:
             print(f"{r['name']},{r['us_per_call']:.1f},{r['derived']}")
@@ -62,7 +69,7 @@ def main() -> None:
         try:
             rows = fn()
         except Exception as e:
-            print(f"{fn.__name__},0,ERROR={type(e).__name__}:{e}")
+            error_row(fn.__name__, e)
             return
         record = {"time": time.time()}
         for r in rows:
@@ -102,7 +109,7 @@ def main() -> None:
                 print(f"{r['name']},{r['us_per_call']:.1f},{r['derived']}")
             print(f"# wrote BENCH artifact of {mod_name}", file=sys.stderr)
         except Exception as e:
-            print(f"{fn_name},0,ERROR={type(e).__name__}:{e}")
+            error_row(fn_name, e)
 
     run_self_writing_bench("bench_rnnt_loss", "bench_rnnt_loss")
     run_self_writing_bench("bench_sharded_epoch", "bench_sharded_epoch")
@@ -121,7 +128,7 @@ def main() -> None:
                   f"dom={t['dominant']};flops={rec['flops']:.3e};"
                   f"hbm_bytes={rec['bytes_accessed']:.3e}")
     except Exception as e:
-        print(f"selection_round_records,0,ERROR={type(e).__name__}:{e}")
+        error_row("selection_round_records", e)
 
     # roofline table from dry-run artifacts, if the sweep has run
     art = os.path.join(os.path.dirname(__file__), "..", "artifacts",
@@ -136,7 +143,11 @@ def main() -> None:
                   f"dom={t['dominant']};roofline={100*t['roofline_fraction']:.1f}%;"
                   f"useful={t['useful_ratio'] and round(t['useful_ratio'],2)}")
     print(f"# total {time.time()-t_start:.1f}s", file=sys.stderr)
+    if errors:
+        print(f"# {len(errors)} phase(s) failed: {', '.join(errors)}",
+              file=sys.stderr)
+    return 1 if errors else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -248,6 +248,10 @@ class PGMConfig:
     # streamed paths elsewhere; "pallas"/"xla" force one side ("pallas"
     # off-TPU runs the interpreter — parity/debug only, it is slow).
     kernel_impl: str = "auto"
+    # a failing resident selection round (DESIGN.md §10): "raise" fails
+    # fast; "soft_random" opts into the Pallas -> XLA -> soft-random
+    # degradation ladder
+    on_failure: str = "raise"
 
 
 @dataclass(frozen=True)

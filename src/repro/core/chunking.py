@@ -40,6 +40,17 @@ def resolve_vocab_chunk(V: int, chunk: int) -> int:
 # the CPU reference paths share it rather than special-casing.
 VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 LANE = 128                       # TPU minor-dim tile (fp32 lane count)
+SUBLANE = 8                      # TPU second-minor tile (fp32)
+
+
+def tpu_tile(want: int, full: int, align: int) -> int:
+    """A block size Mosaic accepts along a dim of size ``full``: the
+    whole dim when ``want`` covers it, else ``want`` rounded up to
+    ``align`` (``LANE`` for a minor dim, ``SUBLANE`` for a second-minor
+    one) and capped at the whole dim."""
+    if want >= full:
+        return full
+    return min(-(-int(want) // align) * align, full)
 
 
 def auto_vocab_chunk(n_rows: int, V: int, *, dtype_bytes: int = 4,

@@ -176,7 +176,9 @@ def rnnt_joint_grad(bundle, params, batch, shard=None) -> jax.Array:
     def loss_of_head(w_out):
         per_ex = rnnt_loss_fused(ze, zp, w_out, batch["tokens"], t_lens,
                                  batch["token_lens"],
-                                 vocab_chunk=r.loss_vocab_chunk)
+                                 vocab_chunk=r.loss_vocab_chunk,
+                                 lattice_part=shard.batch_partition(
+                                     ze.shape[0]))
         return jnp.sum(per_ex * scale)
 
     return jax.grad(loss_of_head)(

@@ -196,13 +196,15 @@ class ResidentSelector:
     re-materialized per call.  With a mesh, stage B additionally routes
     through ``pgm_select_sharded`` exactly like ``pgm_select``.
 
-    Failure ladder (DESIGN.md §10): a round that raises on the resolved
-    Pallas backend falls back *once* (warn-once) to the bit-identical
-    XLA path — both stage A (re-jitted) and stage B read the updated
-    ``kernel_impl`` — and if the scorer still fails the round degrades
-    to a soft-random subset (``on_failure="soft_random"``, the default)
-    rather than killing a multi-epoch run; ``on_failure="raise"``
-    restores fail-fast semantics for tests and debugging.
+    Failure policy (DESIGN.md §10): by default (``on_failure="raise"``)
+    a failing round raises, so a kernel the backend refuses stops the
+    run instead of being replaced unseen.  ``on_failure="soft_random"``
+    opts into the degradation ladder: a round that raises on the
+    resolved Pallas backend falls back *once* (warn-once) to the
+    bit-identical XLA path — both stage A (re-jitted) and stage B read
+    the updated ``kernel_impl`` — and if the scorer still fails the
+    round degrades to a soft-random subset rather than killing a
+    multi-epoch run.
 
     Usage (see ``train/loop.py``)::
 
@@ -213,7 +215,10 @@ class ResidentSelector:
     def __init__(self, bundle, pgm_cfg, proj: Optional[Projections] = None,
                  *, chunk_units: Optional[int] = None, mesh=None,
                  data_axis: str = "data", vocab_chunk: int = 8192,
-                 on_failure: str = "soft_random", log_fn=None):
+                 on_failure: str = "raise", shard=None, log_fn=None):
+        if on_failure not in ("raise", "soft_random"):
+            raise ValueError(f"on_failure must be 'raise' or 'soft_random', "
+                             f"got {on_failure!r}")
         self.bundle = bundle
         self.cfg = pgm_cfg
         self.mesh = mesh
@@ -225,6 +230,9 @@ class ResidentSelector:
         self._vocab_chunk = vocab_chunk
         self._exact = not pgm_cfg.use_sketch
         self._rt = _router_term_for(bundle, pgm_cfg)
+        # the engine's activation sharder: RNN-T stage A runs the fused
+        # loss, whose Pallas lattice needs the mesh's batch partition
+        self._shard = shard if bundle.cfg.family == "rnnt" else None
         impl = _impl_of(pgm_cfg)
         # resolve once at build time and surface the decision: "auto" is
         # data-dependent (TPU vs host), and a silent wrong backend is
@@ -241,13 +249,13 @@ class ResidentSelector:
     def _build_stage_a(self, impl):
         bundle, proj = self.bundle, self._proj
         chunk_units, vocab_chunk = self._chunk_units, self._vocab_chunk
-        exact, rt = self._exact, self._rt
+        exact, rt, shard = self._exact, self._rt, self._shard
 
         def stage_a(params, units):
             return units_gradients_batched(
                 bundle, params, units, proj, chunk_units=chunk_units,
-                vocab_chunk=vocab_chunk, exact=exact, router_term=rt,
-                kernel_impl=impl)
+                shard=shard, vocab_chunk=vocab_chunk, exact=exact,
+                router_term=rt, kernel_impl=impl)
 
         # one jit for train and val units alike: the cache keys on unit
         # shapes, so each distinct corpus compiles once and every later
@@ -270,6 +278,8 @@ class ResidentSelector:
 
     def __call__(self, params, units, val_units=None) -> Selection:
         self._round += 1
+        if self.on_failure == "raise":
+            return self._select_round(params, units, val_units)
         try:
             return self._select_round(params, units, val_units)
         except Exception as err:
@@ -286,8 +296,6 @@ class ResidentSelector:
                     return self._select_round(params, units, val_units)
                 except Exception as err2:
                     err = err2
-            if self.on_failure != "soft_random":
-                raise err
             self.degraded_rounds += 1
             n_units = jax.tree.leaves(units)[0].shape[0]
             self._log(f"warning: selection scorer failed ({err}); "
@@ -316,9 +324,7 @@ def pgm_select_sharded(mesh, axis: str, g_units, pgm_cfg, g_val=None):
 
     g_units: (n, D) global array (sharded on axis 0 by the caller).
     """
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from repro.compat import shard_map
+    from jax.sharding import PartitionSpec as P
 
     n = g_units.shape[0]
     size = mesh.shape[axis]
@@ -345,7 +351,7 @@ def pgm_select_sharded(mesh, axis: str, g_units, pgm_cfg, g_val=None):
 
     gv = (jnp.zeros((1, g_units.shape[1]), jnp.float32) if g_val is None
           else g_val[None])
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=(P(), P(), P(), P()),

@@ -87,21 +87,33 @@ def lattice_scan_ref(mult, add, emit):
     return rows
 
 
-def _lattice(mult, add, emit, impl: str):
+def _lattice(mult, add, emit, impl: str, part=None):
     """Backend dispatch for the lattice scan: ``ref`` (XLA associative
     scan), ``pallas``/``interpret`` (the ``kernels/rnnt_lattice`` kernel,
     compiled / interpret-mode), or ``auto`` (Pallas on TPU, ref
-    elsewhere)."""
-    if impl == "ref":
-        return lattice_scan_ref(mult, add, emit)
-    if impl not in ("auto", "pallas", "interpret"):
+    elsewhere).  ``part`` is a ``(mesh, batch axes)`` pair
+    (``Sharder.batch_partition``): GSPMD cannot partition a Pallas
+    kernel, so on a mesh the kernel runs under ``shard_map``, one slice
+    of the batch axis per device (rows are independent across B)."""
+    if impl not in ("auto", "ref", "pallas", "interpret"):
         raise ValueError(f"lattice_impl must be 'auto', 'ref', 'pallas' "
                          f"or 'interpret', got {impl!r}")
+    from repro.kernels.backend import on_tpu
     from repro.kernels.rnnt_lattice.ops import rnnt_lattice_op
-    if impl == "auto":
-        return rnnt_lattice_op(mult, add, emit)
-    return rnnt_lattice_op(mult, add, emit, use_pallas=True,
-                           interpret=(impl == "interpret"))
+    if impl == "ref" or (impl == "auto" and not on_tpu()):
+        return lattice_scan_ref(mult, add, emit)
+
+    def kernel(m, a, e):
+        return rnnt_lattice_op(m, a, e, use_pallas=True,
+                               interpret=(impl == "interpret"))
+
+    if part is None:
+        return kernel(mult, add, emit)
+    from jax.sharding import PartitionSpec as P
+    mesh, axes = part
+    spec = P(None, axes, None)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(mult, add, emit)
 
 
 # ---------------------------------------------------------------------------
@@ -218,7 +230,7 @@ def _alpha_inputs(lpb, lpe):
     return mult, add, emit
 
 
-def _fused_forward(blank, vocab_chunk, impl, ze, zp, w_out,
+def _fused_forward(blank, vocab_chunk, impl, part, ze, zp, w_out,
                    labels, t_lens, u_lens):
     """Stream the joint over T rows -> (nll, lpb, lpe, logz, alphas)."""
     B, T, J = ze.shape
@@ -235,7 +247,7 @@ def _fused_forward(blank, vocab_chunk, impl, ze, zp, w_out,
 
     _, (lpb, lpe, logz) = jax.lax.scan(row, None, jnp.moveaxis(ze, 1, 0))
 
-    alphas = _lattice(*_alpha_inputs(lpb, lpe), impl)           # (T,B,U1)
+    alphas = _lattice(*_alpha_inputs(lpb, lpe), impl, part)     # (T,B,U1)
     t_idx = jnp.clip(t_lens - 1, 0, T - 1)
     bidx = jnp.arange(B)
     a_final = alphas[t_idx, bidx]                               # (B,U1)
@@ -246,23 +258,24 @@ def _fused_forward(blank, vocab_chunk, impl, ze, zp, w_out,
     return nll, (lpb, lpe, logz, alphas)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
-def _rnnt_fused(blank, vocab_chunk, impl, ze, zp, w_out,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _rnnt_fused(blank, vocab_chunk, impl, part, ze, zp, w_out,
                 labels, t_lens, u_lens):
-    nll, _ = _fused_forward(blank, vocab_chunk, impl, ze, zp, w_out,
+    nll, _ = _fused_forward(blank, vocab_chunk, impl, part, ze, zp, w_out,
                             labels, t_lens, u_lens)
     return nll
 
 
-def _rnnt_fused_fwd(blank, vocab_chunk, impl, ze, zp, w_out,
+def _rnnt_fused_fwd(blank, vocab_chunk, impl, part, ze, zp, w_out,
                     labels, t_lens, u_lens):
     nll, (lpb, lpe, logz, alphas) = _fused_forward(
-        blank, vocab_chunk, impl, ze, zp, w_out, labels, t_lens, u_lens)
+        blank, vocab_chunk, impl, part, ze, zp, w_out, labels, t_lens,
+        u_lens)
     return nll, (ze, zp, w_out, labels, t_lens, u_lens,
                  lpb, lpe, logz, alphas, nll)
 
 
-def _rnnt_fused_bwd(blank, vocab_chunk, impl, res, g):
+def _rnnt_fused_bwd(blank, vocab_chunk, impl, part, res, g):
     """Beta lattice + closed-form occupancy gradient, streamed over T rows
     and vocab chunks into (dze, dzp, dw_out) — the (B,T,U1,V) logits
     gradient is never materialized."""
@@ -280,7 +293,7 @@ def _rnnt_fused_bwd(blank, vocab_chunk, impl, res, g):
                 & (u_ids == u_lens[None, :, None]))             # (T,B,U1)
     term = jnp.where(terminal, lpb, NEG)
     flip = lambda x: x[::-1, :, ::-1]
-    betas = flip(_lattice(flip(lpb), flip(term), flip(lpe), impl))
+    betas = flip(_lattice(flip(lpb), flip(term), flip(lpe), impl, part))
 
     # --- arc posteriors ----------------------------------------------------
     logp = -nll                                                 # (B,)
@@ -361,6 +374,7 @@ def rnnt_loss_fused(
     blank: int = 0,
     vocab_chunk: int = 0,
     lattice_impl: str = "auto",
+    lattice_part=None,
 ) -> jax.Array:
     """Per-example RNN-T NLL from the joint *factors* — the fused,
     memory-lean equivalent of
@@ -371,9 +385,12 @@ def rnnt_loss_fused(
     ``O(B·U·vocab_chunk)`` (``<= 0`` means one chunk of the full vocab),
     and gradients are analytic (``jax.custom_vjp``) so the row scan
     leaves no autodiff residuals.  ``lattice_impl`` selects the lattice
-    backend (``auto`` | ``ref`` | ``pallas`` | ``interpret``).
+    backend (``auto`` | ``ref`` | ``pallas`` | ``interpret``);
+    ``lattice_part`` is the ``(mesh, batch axes)`` a Pallas lattice runs
+    under on a mesh (``Sharder.batch_partition``).
     """
     return _rnnt_fused(int(blank), int(vocab_chunk), str(lattice_impl),
+                       lattice_part,
                        ze.astype(jnp.float32), zp.astype(jnp.float32),
                        w_out.astype(jnp.float32), labels,
                        t_lens.astype(jnp.int32), u_lens.astype(jnp.int32))

@@ -26,7 +26,10 @@ Two sequential-grid kernels:
 The vocab tile ``tv`` defaults to the shared VMEM-budget resolver
 (``core/chunking.py:auto_vocab_chunk`` with ``tn + d`` live rows — the
 (tn, tv) logits tile plus the (d, tv) head slab), the same resolver the
-engine uses to auto-tune the fused RNN-T loss's ``loss_vocab_chunk``.
+engine uses to auto-tune the fused RNN-T loss's ``loss_vocab_chunk``; an
+explicit ``tv`` is capped at it.  Tiles are rounded to what Mosaic
+accepts (``core/chunking.py:tpu_tile``): ``tn`` to sublanes, ``tv`` to
+lanes, unless either spans its whole axis.
 """
 from __future__ import annotations
 
@@ -37,7 +40,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.chunking import auto_vocab_chunk
+from repro.core.chunking import LANE, SUBLANE, auto_vocab_chunk, tpu_tile
 
 NEG = -1e30
 
@@ -56,10 +59,10 @@ def _lse_kernel(h_ref, w_ref, logz_ref, m_ref, s_ref, *, v_total, tv):
     col = j * tv + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
     logits = jnp.where(col < v_total, logits, NEG)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, logits.max(axis=1))
+    m_prev = m_ref[...]                             # (TN, 1)
+    m_new = jnp.maximum(m_prev, logits.max(axis=1, keepdims=True))
     s_ref[...] = (s_ref[...] * jnp.exp(m_prev - m_new)
-                  + jnp.exp(logits - m_new[:, None]).sum(axis=1))
+                  + jnp.exp(logits - m_new).sum(axis=1, keepdims=True))
     m_ref[...] = m_new
 
     @pl.when(j == pl.num_programs(2) - 1)
@@ -85,20 +88,19 @@ def _sketch_kernel(h_ref, w_ref, rv_ref, logz_ref, rvt_ref, scale_ref,
     w = w_ref[...].astype(jnp.float32)
     logits = h @ w                                  # (TN, TV)
     col = j * tv + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-    p = jnp.where(col < v_total,
-                  jnp.exp(logits - logz_ref[0][:, None]), 0.0)
+    p = jnp.where(col < v_total, jnp.exp(logits - logz_ref[0]), 0.0)
     er2_ref[...] += p @ rv_ref[...].astype(jnp.float32)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _():
         er2 = (er2_ref[...] - rvt_ref[0].astype(jnp.float32))
-        er2 = er2 * scale_ref[0][:, None]
+        er2 = er2 * scale_ref[0]
         out_ref[...] += (hr_ref[0].astype(jnp.float32).T @ er2)[None]
 
 
 @functools.partial(jax.jit, static_argnames=("tn", "tv", "interpret"))
 def grad_sketch_units(h, w, r_h, r_v, targets, scale, *, tn: int = 256,
-                      tv: int = 0, interpret: bool = True):
+                      tv: int = 0, interpret: bool):
     """h (U,n,d); w (d,V); r_h (d,k1); r_v (V,k2); targets (U,n);
     scale (U,n) -> per-unit sketches (U, k1, k2) fp32.
 
@@ -108,8 +110,11 @@ def grad_sketch_units(h, w, r_h, r_v, targets, scale, *, tn: int = 256,
     U, n, d = h.shape
     V = w.shape[1]
     k1, k2 = r_h.shape[1], r_v.shape[1]
-    tn = min(tn, max(n, 8))
-    tv = auto_vocab_chunk(tn + d, V) if tv <= 0 else min(tv, V)
+    tn = tpu_tile(tn, n, SUBLANE)
+    # the VMEM budget caps an explicit vocab tile too: a (d, tv) head
+    # slab past it is refused by the TPU compiler
+    auto = auto_vocab_chunk(tn + d, V)
+    tv = tpu_tile(auto if tv <= 0 else min(tv, auto), V, LANE)
 
     n_pad = (-n) % tn
     v_pad = (-V) % tv
@@ -117,7 +122,10 @@ def grad_sketch_units(h, w, r_h, r_v, targets, scale, *, tn: int = 256,
     wp = jnp.pad(w, ((0, 0), (0, v_pad)))
     rvp = jnp.pad(r_v, ((0, v_pad), (0, 0)))
     tp = jnp.pad(targets, ((0, 0), (0, n_pad)))
-    sp = jnp.pad(scale, ((0, 0), (0, n_pad)))
+    # per-row scalars ride as (U, rows, 1) columns: a (1, tn) block of a
+    # (U, rows) array has a second-minor dim of 1 != U, which Mosaic
+    # refuses, and the column broadcasts against (tn, tv) tiles as is
+    sp = jnp.pad(scale, ((0, 0), (0, n_pad)))[..., None]
     np_, Vp = n + n_pad, V + v_pad
     gn, gv = np_ // tn, Vp // tv
 
@@ -132,10 +140,10 @@ def grad_sketch_units(h, w, r_h, r_v, targets, scale, *, tn: int = 256,
             pl.BlockSpec((1, tn, d), lambda u, i, j: (u, i, 0)),
             pl.BlockSpec((d, tv), lambda u, i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((1, tn), lambda u, i, j: (u, i)),
-        out_shape=jax.ShapeDtypeStruct((U, np_), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((tn,), jnp.float32),
-                        pltpu.VMEM((tn,), jnp.float32)],
+        out_specs=pl.BlockSpec((1, tn, 1), lambda u, i, j: (u, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((U, np_, 1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((tn, 1), jnp.float32),
+                        pltpu.VMEM((tn, 1), jnp.float32)],
         interpret=interpret,
     )(hp, wp)
 
@@ -146,9 +154,9 @@ def grad_sketch_units(h, w, r_h, r_v, targets, scale, *, tn: int = 256,
             pl.BlockSpec((1, tn, d), lambda u, i, j: (u, i, 0)),
             pl.BlockSpec((d, tv), lambda u, i, j: (0, j)),
             pl.BlockSpec((tv, k2), lambda u, i, j: (j, 0)),
-            pl.BlockSpec((1, tn), lambda u, i, j: (u, i)),
+            pl.BlockSpec((1, tn, 1), lambda u, i, j: (u, i, 0)),
             pl.BlockSpec((1, tn, k2), lambda u, i, j: (u, i, 0)),
-            pl.BlockSpec((1, tn), lambda u, i, j: (u, i)),
+            pl.BlockSpec((1, tn, 1), lambda u, i, j: (u, i, 0)),
             pl.BlockSpec((1, tn, k1), lambda u, i, j: (u, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, k1, k2), lambda u, i, j: (u, 0, 0)),
@@ -161,7 +169,7 @@ def grad_sketch_units(h, w, r_h, r_v, targets, scale, *, tn: int = 256,
 
 @functools.partial(jax.jit, static_argnames=("tn", "tv", "interpret"))
 def grad_sketch(h, w, r_h, r_v, targets, scale, *, tn: int = 256,
-                tv: int = 0, interpret: bool = True):
+                tv: int = 0, interpret: bool):
     """h (N,d); w (d,V); r_h (d,k1); r_v (V,k2); targets (N,); scale (N,)
     -> sketch (k1, k2) fp32.  The U = 1 case of ``grad_sketch_units``."""
     return grad_sketch_units(h[None], w, r_h, r_v, targets[None],

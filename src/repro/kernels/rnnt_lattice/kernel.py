@@ -58,7 +58,7 @@ def _lattice_kernel(mult_ref, add_ref, emit_ref, out_ref, carry_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def rnnt_lattice(mult, add, emit, *, interpret: bool = True):
+def rnnt_lattice(mult, add, emit, *, interpret: bool):
     """mult, add, emit: (T, B, U1) fp32 -> lattice rows (T, B, U1) fp32.
 
     ``emit[t, :, 0]`` must be NEG (position 0 has no within-row

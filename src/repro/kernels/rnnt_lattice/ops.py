@@ -5,14 +5,9 @@ lattice backend); same dispatch convention as ``grad_sketch``/
 ``omp_gram``."""
 from __future__ import annotations
 
-import jax
-
+from repro.kernels.backend import on_tpu
 from repro.kernels.rnnt_lattice.kernel import rnnt_lattice as _pallas_lattice
 from repro.kernels.rnnt_lattice.ref import rnnt_lattice_ref
-
-
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def rnnt_lattice_op(mult, add, emit, *, use_pallas: bool = None,

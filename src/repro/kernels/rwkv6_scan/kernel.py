@@ -69,7 +69,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, y_ref, sout_ref, s_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def rwkv6_wkv(r, k, v, w, u, *, chunk: int = 64, interpret: bool = True):
+def rwkv6_wkv(r, k, v, w, u, *, chunk: int = 64, interpret: bool):
     """r,k,v,w: (B, S, H, N); u: (H, N).  w = decays in (0,1).
     Returns (y (B,S,H,N) fp32, final state (B,H,N,N))."""
     B, S, H, N = r.shape

@@ -58,7 +58,7 @@ def _swa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("window", "tq", "interpret"))
-def swa_attn(q, k, v, *, window: int, tq: int = 256, interpret: bool = True):
+def swa_attn(q, k, v, *, window: int, tq: int = 256, interpret: bool):
     """q,k,v: (B, H, S, hd), causal sliding-window of ``window`` positions
     (q attends to kv in (q-window, q]).  Returns (B, H, S, hd)."""
     B, H, S, hd = q.shape

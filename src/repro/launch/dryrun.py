@@ -9,9 +9,12 @@ Usage:
   python -m repro.launch.dryrun --arch rwkv6-3b --shape long_500k --multi-pod
   python -m repro.launch.dryrun --list
 """
-# The next two lines MUST run before any other import (jax locks the device
-# count on first init).  REPRO_DRYRUN_DEVICES overrides for small CI runs.
+# The next lines MUST run before any other import (jax locks the platform
+# and device count on first init): the dry run compiles for forced host
+# devices, so it is pinned to the CPU and never takes an accelerator.
+# REPRO_DRYRUN_DEVICES overrides the device count for small CI runs.
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=" +
                            os.environ.get("REPRO_DRYRUN_DEVICES", "512"))

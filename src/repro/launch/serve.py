@@ -20,6 +20,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.cache import enable_compile_cache
 from repro.models.api import build_model
 from repro.serve.engine import Request, SlotEngine, generate
 
@@ -87,6 +88,7 @@ def main():
     ap.add_argument("--sync-every", type=int, default=4)
     ap.add_argument("--eos-id", type=int, default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     bundle = build_model(cfg)

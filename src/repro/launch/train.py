@@ -35,6 +35,8 @@ from repro.configs import get_config
 from repro.configs.base import PGMConfig, TrainConfig
 from repro.data.pipeline import asr_units, lm_units
 from repro.data.synthetic import make_asr_corpus, make_lm_corpus
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models.api import build_model
 from repro.train.loop import History, train_with_selection
 
@@ -54,7 +56,7 @@ def parse_mesh(spec: Optional[str], axes: str = "data,model"):
     if len(dims) != 2 or len(names) != 2:
         raise ValueError(f"mesh spec must be AxB over two named axes, "
                          f"got {spec!r} over {axes!r}")
-    return jax.make_mesh(dims, names)
+    return make_mesh(dims, names)
 
 
 def make_units_for(cfg, *, n: int, seq: int, noise: float, seed: int = 0,
@@ -198,6 +200,7 @@ def main():
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     tc = TrainConfig(
         lr=args.lr, optimizer=args.optimizer, epochs=args.epochs,

@@ -344,7 +344,8 @@ def _build_rnnt(cfg: ModelConfig) -> ModelBundle:
             return rnnt_loss_fused(
                 ze, zp, params["joint"]["w_out"], batch["tokens"],
                 _t_lens(batch), batch["token_lens"],
-                vocab_chunk=r.loss_vocab_chunk)
+                vocab_chunk=r.loss_vocab_chunk,
+                lattice_part=shard.batch_partition(ze.shape[0]))
         logits = rnnt_mod.forward(params, cfg, batch["feats"], batch["tokens"])
         return rnnt_loss_from_logits(logits, batch["tokens"], _t_lens(batch),
                                      batch["token_lens"])

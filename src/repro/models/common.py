@@ -29,6 +29,12 @@ class Sharder:
         EXPERIMENTS.md §Perf).  Identity sharder: never."""
         return 1
 
+    def batch_partition(self, n: int):
+        """``(mesh, axes)`` over which a batch dim of size ``n`` is split
+        for a Pallas kernel, which GSPMD cannot partition and which must
+        therefore run under ``shard_map``; None without a mesh."""
+        return None
+
 
 IDENTITY_SHARDER = Sharder()
 

@@ -324,6 +324,12 @@ class MeshSharder(Sharder):
                 return r
         return 1
 
+    def batch_partition(self, n: int):
+        if not self.enable:
+            return None
+        dp = self.b.dp
+        return self.mesh, (dp if _div(n, self.mesh, dp) else None)
+
     def __call__(self, x, name: str):
         if not self.enable:
             return x

@@ -452,7 +452,7 @@ class EpochEngine:
         pod = self._pod
         guard = self.guard
 
-        def make_body(lr):
+        def make_body(lr, units):
             def body(carry, xs):
                 if guard:
                     *carry, nsk = carry
@@ -464,12 +464,7 @@ class EpochEngine:
                 # plan rows are wholly real or wholly padding; padding
                 # rows carry id -1 / weight 0 and must be bit-exact no-ops
                 live = idx[0] >= 0
-                gidx = jnp.maximum(idx, 0)
-                batch = {
-                    k: v[gidx].reshape((-1,) + v.shape[2:])
-                    for k, v in self.units.items()
-                }
-                batch = self._constrain_batch(batch)
+                batch = self.gather_batch(units, idx)
                 if "weights" in batch:
                     batch = dict(batch, weights=batch["weights"]
                                  * jnp.repeat(w, unit_size))
@@ -492,34 +487,39 @@ class EpochEngine:
 
             return body
 
-        def scan_epoch(carry, lr, xs):
+        def scan_epoch(carry, lr, xs, units):
             """One epoch scan; normalizes the guard-on/-off carry and ys
             shapes to ``(state_carry, losses, skipped, n_skipped)`` with
             ``skipped/n_skipped = None`` when the guard is off."""
             if not guard:
-                carry, losses = jax.lax.scan(make_body(lr), carry, xs)
+                carry, losses = jax.lax.scan(make_body(lr, units), carry,
+                                             xs)
                 return carry, losses, None, None
             (*carry, nsk), (losses, skipped) = jax.lax.scan(
-                make_body(lr), tuple(carry) + (jnp.zeros((), jnp.int32),),
-                xs)
+                make_body(lr, units),
+                tuple(carry) + (jnp.zeros((), jnp.int32),), xs)
             return tuple(carry), losses, skipped, nsk
 
+        # the resident units are an argument of every dispatch, never a
+        # closed-over constant: a closure would embed the whole corpus
+        # in the executable, unsharded
         if pod is None:
-            def run(params, opt_state, batch_idx, batch_w, lr):
+            def run(params, opt_state, batch_idx, batch_w, lr, units):
                 params, opt_state = self._constrain_state(params, opt_state)
                 (params, opt_state), losses, skipped, nsk = scan_epoch(
-                    (params, opt_state), lr, (batch_idx, batch_w))
+                    (params, opt_state), lr, (batch_idx, batch_w), units)
                 return params, opt_state, losses, skipped, nsk
 
             # donate (params, opt_state): the scan carry re-uses their
             # buffers
             self._run = jax.jit(run, donate_argnums=(0, 1))
         else:
-            def run(params, opt_state, err, batch_idx, batch_w, lr):
+            def run(params, opt_state, err, batch_idx, batch_w, lr, units):
                 params, opt_state = self._constrain_state(params, opt_state)
                 err = self._constrain_err(err)
                 (params, opt_state, err), losses, skipped, nsk = scan_epoch(
-                    (params, opt_state, err), lr, (batch_idx, batch_w))
+                    (params, opt_state, err), lr, (batch_idx, batch_w),
+                    units)
                 return params, opt_state, err, losses, skipped, nsk
 
             # the per-pod error-feedback residuals join the donated carry
@@ -541,13 +541,13 @@ class EpochEngine:
 
         self._validate = jax.jit(val_mean)
 
-        def chunk_epoch_body(state_carry, val_dev, lr_c, prev, xs):
+        def chunk_epoch_body(state_carry, val_dev, lr_c, prev, xs, units):
             """Shared inner body of the chunked dispatch: one epoch scan
             + validation + newbob.  Returns the updated state carry, lr,
             prev, the epoch skip count, and this epoch's ys (losses
             [, skip mask], val loss, lr)."""
             state_carry, losses, skipped, nsk = scan_epoch(state_carry,
-                                                           lr_c, xs)
+                                                           lr_c, xs, units)
             p = state_carry[0]
             if val_dev is not None:
                 vl = val_mean(p, val_dev)
@@ -563,7 +563,7 @@ class EpochEngine:
 
         if pod is None:
             def run_chunk(params, opt_state, val_dev, batch_idx, batch_w,
-                          lr, prev_loss):
+                          lr, prev_loss, units):
                 """batch_idx/batch_w: (n_epochs, n_steps, batch_units).
                 The whole chunk — epochs, validations, newbob updates —
                 is one dispatch; metrics are accumulated in the scan ys
@@ -576,7 +576,7 @@ class EpochEngine:
                     else:
                         p, s, lr_c, prev = carry
                     (p, s), lr_n, prev, nsk_e, ys = chunk_epoch_body(
-                        (p, s), val_dev, lr_c, prev, xs)
+                        (p, s), val_dev, lr_c, prev, xs, units)
                     if guard:
                         return (p, s, lr_n, prev, nsk + nsk_e), ys
                     return (p, s, lr_n, prev), ys
@@ -598,7 +598,7 @@ class EpochEngine:
             self._run_chunk = jax.jit(run_chunk, donate_argnums=(0, 1))
         else:
             def run_chunk(params, opt_state, err, val_dev, batch_idx,
-                          batch_w, lr, prev_loss):
+                          batch_w, lr, prev_loss, units):
                 """Pod-mode chunk: identical dispatch shape, with the
                 per-pod error-feedback residuals threaded through the
                 outer epoch carry next to (params, opt_state)."""
@@ -611,7 +611,7 @@ class EpochEngine:
                     else:
                         p, s, e, lr_c, prev = carry
                     (p, s, e), lr_n, prev, nsk_e, ys = chunk_epoch_body(
-                        (p, s, e), val_dev, lr_c, prev, xs)
+                        (p, s, e), val_dev, lr_c, prev, xs, units)
                     if guard:
                         return (p, s, e, lr_n, prev, nsk + nsk_e), ys
                     return (p, s, e, lr_n, prev), ys
@@ -642,6 +642,15 @@ class EpochEngine:
         # make_step_core.split_pods)
         place = _data_sharded_put(self.mesh, self.data_axis)
         return {k: place(jnp.asarray(v)) for k, v in units.items()}
+
+    def gather_batch(self, units, idx):
+        """The step's batch for one plan row ``idx`` (padding ids -1 read
+        unit 0 and are gated off by weight), example axis data-sharded
+        on a mesh."""
+        gidx = jnp.maximum(idx, 0)
+        batch = {k: v[gidx].reshape((-1,) + v.shape[2:])
+                 for k, v in units.items()}
+        return self._constrain_batch(batch)
 
     def _constrain_batch(self, batch):
         """Shard the gathered batch's example axis over ``data`` (when
@@ -817,18 +826,29 @@ class EpochEngine:
         The passed params/opt_state buffers are donated (see class
         docstring); in pod mode the engine-held ``compress_state`` is
         donated and replaced alongside them."""
-        batch_idx, batch_w = plan
+        args = self._epoch_args(params, opt_state, lr, plan)
         if self._pod is None:
-            params, opt_state, losses, skipped, nsk = self._run(
-                params, opt_state, batch_idx, batch_w,
-                jnp.asarray(lr, jnp.float32))
+            params, opt_state, losses, skipped, nsk = self._run(*args)
         else:
-            err = self._ensure_compress_state(params)
             (params, opt_state, self.compress_state, losses, skipped,
-             nsk) = self._run(params, opt_state, err, batch_idx, batch_w,
-                              jnp.asarray(lr, jnp.float32))
+             nsk) = self._run(*args)
         self.last_skipped, self.last_n_skipped = skipped, nsk
         return params, opt_state, losses
+
+    def _epoch_args(self, params, opt_state, lr, plan):
+        batch_idx, batch_w = plan
+        head = (params, opt_state)
+        if self._pod is not None:
+            head += (self._ensure_compress_state(params),)
+        return head + (batch_idx, batch_w, jnp.asarray(lr, jnp.float32),
+                       self.units)
+
+    def lower_epoch(self, params, opt_state, lr, plan):
+        """``jax.stages.Lowered`` of the one-epoch dispatch that
+        ``run_epoch`` would make for these arguments (nothing is
+        donated or run) — for compiled-artifact checks."""
+        return self._run.lower(*self._epoch_args(params, opt_state, lr,
+                                                 plan))
 
     def run_epochs(self, params, opt_state, lr, prev_loss,
                    plans: Sequence[Tuple[jax.Array, jax.Array]]):
@@ -858,14 +878,15 @@ class EpochEngine:
              prev_out) = self._run_chunk(params, opt_state, self.val_units,
                                          batch_idx, batch_w,
                                          jnp.asarray(lr, jnp.float32),
-                                         jnp.asarray(prev_loss, jnp.float32))
+                                         jnp.asarray(prev_loss, jnp.float32),
+                                         self.units)
         else:
             err = self._ensure_compress_state(params)
             (params, opt_state, self.compress_state, losses, skipped, nsk,
              vls, lrs, lr_out, prev_out) = self._run_chunk(
                 params, opt_state, err, self.val_units, batch_idx, batch_w,
                 jnp.asarray(lr, jnp.float32),
-                jnp.asarray(prev_loss, jnp.float32))
+                jnp.asarray(prev_loss, jnp.float32), self.units)
         self.last_skipped, self.last_n_skipped = skipped, nsk
         return params, opt_state, losses, vls, lrs, lr_out, prev_out
 

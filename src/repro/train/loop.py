@@ -68,6 +68,10 @@ class History:
     skipped_steps: int = 0         # non-finite steps gated off on device
     rollbacks: int = 0             # divergence-watchdog restores
     preempted: bool = False        # exited early on SIGTERM/SIGINT
+    # resident selection rounds: the kernel backend they ran on and how
+    # many fell back to a soft-random subset (opt-in ladder only)
+    selection_kernels: Optional[str] = None
+    degraded_rounds: int = 0
 
 
 def _max_consecutive(mask: np.ndarray) -> int:
@@ -164,7 +168,10 @@ def train_with_selection(
     # caches its executable (and the projections, closed over the jit)
     # across rounds
     resident = (ResidentSelector(bundle, tc.pgm, proj, mesh=mesh,
-                                 data_axis=data_axis, log_fn=log_fn)
+                                 data_axis=data_axis,
+                                 on_failure=tc.pgm.on_failure,
+                                 shard=getattr(eng, "act_shard", None),
+                                 log_fn=log_fn)
                 if resident_selection and method == "pgm" else None)
 
     hist = History()
@@ -493,4 +500,7 @@ def train_with_selection(
 
     hist.wall_time = time.time() - t0
     hist.final_params = params
+    if resident is not None:
+        hist.selection_kernels = resident.kernel_impl
+        hist.degraded_rounds = resident.degraded_rounds
     return hist

@@ -457,7 +457,6 @@ def test_scan_engine_epoch_executable_satisfies_contracts():
     opt_init, _ = make_update_for(tc)
     p = m.init_params(jax.random.PRNGKey(0))
     o = opt_init(p)
-    idx, w = eng.full_plan(0)
-    low = eng._run.lower(p, o, idx, w, jnp.float32(0.5))
+    low = eng.lower_epoch(p, o, 0.5, eng.full_plan(0))
     contracts.assert_donated(low, (p, o))
     contracts.assert_no_host_transfers(low, low.compile().as_text())

@@ -304,6 +304,7 @@ def test_moe_expert_sharded_engine_smoke():
     single-device engine."""
     out = _run(textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         import jax.tree_util as jtu
         from repro.configs import get_config
         from repro.configs.base import PGMConfig, TrainConfig
@@ -318,7 +319,7 @@ def test_moe_expert_sharded_engine_smoke():
         units = lm_units(make_lm_corpus(0, 16, 10, cfg.vocab_size,
                                         hard_fraction=0.4), 2)
         tc = TrainConfig(lr=0.2, optimizer="sgd", epochs=2, pgm=PGMConfig())
-        mesh = jax.make_mesh((2, 2), ("data", "expert"))
+        mesh = make_mesh((2, 2), ("data", "expert"))
         eng = EpochEngine(m, tc, units, batch_units=2, mesh=mesh,
                           spec_mode="expert")
         opt_init, _ = make_update_for(tc)
@@ -361,6 +362,7 @@ def test_recurrent_sharded_engine_smoke():
     sharded engine within 1e-3 of single device."""
     out = _run(textwrap.dedent("""
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config
         from repro.configs.base import PGMConfig, TrainConfig
         from repro.data.pipeline import lm_units
@@ -374,7 +376,7 @@ def test_recurrent_sharded_engine_smoke():
         units = lm_units(make_lm_corpus(0, 16, 10, cfg.vocab_size,
                                         hard_fraction=0.4), 2)
         tc = TrainConfig(lr=0.2, optimizer="sgd", epochs=2, pgm=PGMConfig())
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = make_mesh((4,), ("data",))
         eng = EpochEngine(m, tc, units, batch_units=2, mesh=mesh)
         opt_init, _ = make_update_for(tc)
         p = m.init_params(jax.random.PRNGKey(0)); o = opt_init(p)

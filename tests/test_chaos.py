@@ -274,7 +274,8 @@ def test_kernel_failure_falls_back_to_bit_identical_xla(lm):
     )(params, units_dev)
     logs = []
     with faults.failing_selection_kernels(("pallas",)):
-        rs = ResidentSelector(bundle, pc, proj, log_fn=logs.append)
+        rs = ResidentSelector(bundle, pc, proj, on_failure="soft_random",
+                              log_fn=logs.append)
         sel = rs(params, units_dev)
         sel2 = rs(params, units_dev)      # later rounds stay on XLA
     assert rs.kernel_impl == "xla"
@@ -296,7 +297,8 @@ def test_total_scorer_failure_degrades_to_soft_random(lm):
     budget = max(int(pc.subset_fraction * n_units), 1)
     logs = []
     with faults.failing_selection_kernels(("all",)):
-        rs = ResidentSelector(bundle, pc, proj, log_fn=logs.append)
+        rs = ResidentSelector(bundle, pc, proj, on_failure="soft_random",
+                              log_fn=logs.append)
         sel = rs(params, units_dev)
     assert rs.degraded_rounds == 1
     assert int(sel.n_selected) == budget
@@ -306,9 +308,21 @@ def test_total_scorer_failure_degrades_to_soft_random(lm):
     assert np.allclose(np.asarray(sel.weights)[idx >= 0], 1.0)
     assert any("soft-random" in l for l in logs)
     with faults.failing_selection_kernels(("all",)):
-        rs2 = ResidentSelector(bundle, pc, proj, on_failure="raise")
+        rs2 = ResidentSelector(bundle, pc, proj)      # default: raise
         with pytest.raises(RuntimeError, match="injected kernel failure"):
             rs2(params, units_dev)
+
+
+def test_selector_fails_fast_by_default(lm):
+    """Without the opt-in ladder a refused Pallas round raises: no silent
+    retry on XLA, no degraded round."""
+    bundle, pc, proj, units_dev, params = _selector_setup(
+        lm, kernel_impl="pallas")
+    with faults.failing_selection_kernels(("pallas",)):
+        rs3 = ResidentSelector(bundle, pc, proj)
+        with pytest.raises(RuntimeError, match="injected kernel failure"):
+            rs3(params, units_dev)
+    assert rs3.kernel_impl == "pallas" and rs3.degraded_rounds == 0
 
 
 @pytest.mark.slow
@@ -317,6 +331,8 @@ def test_training_survives_total_scorer_failure(lm):
     trains to a finite final loss on the soft-random baseline."""
     bundle, units, val = lm
     tc = _tc(epochs=4)
+    tc = dataclasses.replace(
+        tc, pgm=dataclasses.replace(tc.pgm, on_failure="soft_random"))
     with faults.failing_selection_kernels(("all",)):
         h = train_with_selection(
             bundle, units, tc, method="pgm", val_units=val, engine="scan",

@@ -21,7 +21,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.analysis.contracts import assert_collective_width
-from repro.compat import shard_map
+from repro.launch.mesh import make_mesh
 from repro.train.compress import (bf16_compress, compressed_psum,
                                   init_error_state, topk_compress)
 
@@ -167,14 +167,14 @@ def test_unknown_mode_raises():
 def _lowered_compressed_psum(mode):
     """Lowered module of a shard_map'd compressed_psum (1-device 'pod'
     mesh: lowering — unlike compilation — still emits the collective)."""
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = make_mesh((1,), ("pod",))
 
     def f(g):
         red, _ = compressed_psum(g, "pod", mode=mode)
         return red
 
-    sm = shard_map(f, mesh=mesh, in_specs=({"w": P("pod")},),
-                   out_specs={"w": P("pod")})
+    sm = jax.shard_map(f, mesh=mesh, in_specs=({"w": P("pod")},),
+                       out_specs={"w": P("pod")})
     return jax.jit(sm).lower({"w": jnp.ones((8, 4), jnp.float32)})
 
 

@@ -34,6 +34,7 @@ from repro.configs import get_config
 from repro.configs.base import PGMConfig, TrainConfig
 from repro.data.pipeline import lm_units
 from repro.data.synthetic import make_lm_corpus
+from repro.launch.mesh import make_mesh
 from repro.models.api import build_model
 from repro.train.engine import EpochEngine, make_engine
 from repro.train.loop import train_with_selection
@@ -69,7 +70,7 @@ def test_pod_modes_match_plain_engine_on_1x1_mesh():
     m, units, val, tc = _lm_setup()
     h_plain = train_with_selection(m, units, tc, method="full",
                                    val_units=val, engine="scan")
-    mesh = jax.make_mesh((1, 1), ("data", "pod"))
+    mesh = make_mesh((1, 1), ("data", "pod"))
     h_none = train_with_selection(
         m, units, dataclasses.replace(tc, compress_mode="none"),
         method="full", val_units=val, engine="scan", mesh=mesh)
@@ -87,7 +88,7 @@ def test_pod_modes_match_plain_engine_on_1x1_mesh():
 
 def test_topk_engine_error_state_shape_and_donation():
     m, units, _, tc = _lm_setup(compress_mode="topk")
-    mesh = jax.make_mesh((1, 1), ("data", "pod"))
+    mesh = make_mesh((1, 1), ("data", "pod"))
     eng = EpochEngine(m, tc, units, batch_units=2, mesh=mesh)
     assert eng.uses_error_feedback and eng.n_pods == 1
     opt_init, _ = make_update_for(tc)
@@ -108,7 +109,7 @@ def test_padding_steps_leave_error_state_bitwise():
     """An all-padding plan must advance nothing: params, opt state AND
     the error-feedback residuals come back bit-identical."""
     m, units, _, tc = _lm_setup(compress_mode="topk")
-    mesh = jax.make_mesh((1, 1), ("data", "pod"))
+    mesh = make_mesh((1, 1), ("data", "pod"))
     eng = EpochEngine(m, tc, units, batch_units=2, mesh=mesh)
     opt_init, _ = make_update_for(tc)
     p = m.init_params(jax.random.PRNGKey(0))
@@ -138,7 +139,7 @@ def test_recurrent_padding_on_pod_mesh_bitwise():
     tc = TrainConfig(lr=0.2, optimizer="sgd", epochs=1,
                      compress_mode="topk", compress_k_frac=0.1,
                      pgm=PGMConfig())
-    mesh = jax.make_mesh((1, 1), ("data", "pod"))
+    mesh = make_mesh((1, 1), ("data", "pod"))
     eng = EpochEngine(m, tc, units, batch_units=2, mesh=mesh)
     opt_init, _ = make_update_for(tc)
     p = m.init_params(jax.random.PRNGKey(0))
@@ -165,7 +166,7 @@ def test_guard_composes_with_pod_compression_bitwise():
     for guard in (False, True):
         m, units, _, tc = _lm_setup(compress_mode="topk")
         tc = dataclasses.replace(tc, nonfinite_guard=guard)
-        mesh = jax.make_mesh((1, 1), ("data", "pod"))
+        mesh = make_mesh((1, 1), ("data", "pod"))
         eng = EpochEngine(m, tc, units, batch_units=2, mesh=mesh)
         opt_init, _ = make_update_for(tc)
         p = m.init_params(jax.random.PRNGKey(0))
@@ -195,7 +196,7 @@ def test_compress_config_validation():
     # compression without a pod axis on the mesh is a config error …
     with pytest.raises(ValueError, match="pod"):
         EpochEngine(m, tc, units, batch_units=2)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with pytest.raises(ValueError, match="pod"):
         EpochEngine(m, tc, units, batch_units=2, mesh=mesh)
     # … and the host loop refuses it loudly instead of training dense
@@ -215,7 +216,7 @@ def test_resume_across_compress_modes_is_graceful(tmp_path):
     zero — not KeyError on the missing 'err' arrays — and the reverse
     direction must warn about the mode switch."""
     m, units, val, tc = _lm_setup(epochs=2)
-    mesh = jax.make_mesh((1, 1), ("data", "pod"))
+    mesh = make_mesh((1, 1), ("data", "pod"))
     d = str(tmp_path / "ck")
     train_with_selection(
         m, units, dataclasses.replace(tc, compress_mode="none"),
@@ -241,7 +242,7 @@ def test_resume_across_compress_modes_is_graceful(tmp_path):
 
 def test_err_sharding_and_restore_specs():
     m, units, _, tc = _lm_setup(compress_mode="topk")
-    mesh = jax.make_mesh((1, 1), ("data", "pod"))
+    mesh = make_mesh((1, 1), ("data", "pod"))
     eng = EpochEngine(m, tc, units, batch_units=2, mesh=mesh)
     p = m.init_params(jax.random.PRNGKey(0))
     err = eng.init_compress_state(p)
@@ -275,6 +276,7 @@ def _run(code: str) -> str:
 _SETUP = """
 import dataclasses
 import numpy as np, jax
+from repro.launch.mesh import make_mesh
 from repro.configs import get_config
 from repro.configs.base import PGMConfig, TrainConfig
 from repro.data.pipeline import lm_units
@@ -287,7 +289,7 @@ m = build_model(cfg)
 units = lm_units(make_lm_corpus(0, 32, 12, cfg.vocab_size,
                                 hard_fraction=0.4), 4)
 val = lm_units(make_lm_corpus(7, 16, 12, cfg.vocab_size), 4)
-pod_mesh = jax.make_mesh((2, 2), ("data", "pod"))
+pod_mesh = make_mesh((2, 2), ("data", "pod"))
 """
 
 
@@ -305,7 +307,7 @@ def test_pod_none_matches_gspmd_only_engine():
         h1 = train_with_selection(m, units, tc, method="pgm",
                                   val_units=val, engine="scan",
                                   batch_units=2)
-        gspmd = jax.make_mesh((2, 2), ("data", "model"))
+        gspmd = make_mesh((2, 2), ("data", "model"))
         h2 = train_with_selection(m, units, tc, method="pgm",
                                   val_units=val, engine="scan",
                                   mesh=gspmd, batch_units=2)
@@ -335,8 +337,12 @@ def test_pod_none_matches_gspmd_only_engine():
 @pytest.mark.slow
 def test_pod_topk_trains_within_5pct_of_dense():
     """Top-k (10% of entries per leaf) + error feedback must reach a
-    final validation loss within 5% relative of the dense pod run on the
-    LM smoke — the convergence-preservation claim of Stich et al."""
+    final training loss within 5% relative of the dense pod run on the
+    LM smoke — the convergence-preservation claim of Stich et al. is a
+    claim about the optimized objective.  (This 32-sequence corpus
+    overfits from epoch 5 on: validation loss rises while training loss
+    falls, so the final validation loss measures overfitting noise, not
+    convergence.)"""
     out = _run(_SETUP + textwrap.dedent("""
         base = TrainConfig(lr=0.3, optimizer="sgd", epochs=8,
                            pgm=PGMConfig())
@@ -347,7 +353,7 @@ def test_pod_topk_trains_within_5pct_of_dense():
             h = train_with_selection(m, units, tc, method="full",
                                      val_units=val, engine="scan",
                                      mesh=pod_mesh, batch_units=2)
-            finals[mode] = h.val_loss[-1]
+            finals[mode] = h.train_loss[-1]
         rel_topk = abs(finals["topk"] - finals["none"]) / finals["none"]
         rel_bf16 = abs(finals["bf16"] - finals["none"]) / finals["none"]
         assert rel_topk <= 0.05, (finals, rel_topk)
@@ -409,6 +415,7 @@ def test_pod_step_hlo_collective_and_divisibility():
     Indivisible per-pod batches are a build-time error."""
     out = _run(textwrap.dedent("""
         import numpy as np, jax, jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
         from repro.analysis.contracts import (
             assert_collective_width, assert_donated,
             assert_no_host_transfers, assert_replica_groups)
@@ -425,14 +432,13 @@ def test_pod_step_hlo_collective_and_divisibility():
         tc = TrainConfig(lr=0.5, optimizer="sgd", epochs=1,
                          compress_mode="bf16", pgm=PGMConfig())
         for shape in ((2, 2), (1, 4)):
-            mesh = jax.make_mesh(shape, ("data", "pod"))
+            mesh = make_mesh(shape, ("data", "pod"))
             eng = EpochEngine(m, tc, units, batch_units=2, mesh=mesh)
             opt_init, _ = make_update_for(tc)
             p = m.init_params(jax.random.PRNGKey(0))
             o = opt_init(p)
             p, o = eng.shard_state(p, o)
-            idx, w = eng.full_plan(0)
-            low = eng._run.lower(p, o, None, idx, w, jnp.float32(0.5))
+            low = eng.lower_epoch(p, o, 0.5, eng.full_plan(0))
             n_leaves = len(jax.tree.leaves(p))
             # wire width: one bf16 pod reduce per gradient leaf, read
             # off the lowered module (XLA:CPU float-normalization
@@ -448,7 +454,7 @@ def test_pod_step_hlo_collective_and_divisibility():
             # the whole epoch dispatch stays device-resident
             assert_no_host_transfers(low, txt)
         # unit_size=3 batches cannot split across 2 pods
-        mesh = jax.make_mesh((2, 2), ("data", "pod"))
+        mesh = make_mesh((2, 2), ("data", "pod"))
         units_odd = lm_units(make_lm_corpus(0, 16, 10, cfg.vocab_size), 3)
         try:
             EpochEngine(m, tc, units_odd, batch_units=1, mesh=mesh)

@@ -60,16 +60,16 @@ def test_grad_sketch_op_jnp_path_matches():
 # ---------------------------------------------------------------------------
 # omp_gram
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("n,D,ti,td,dtype",
+@pytest.mark.parametrize("n,D,tn,td,dtype",
                          [(40, 100, 16, 64, jnp.float32),
                           (130, 257, 64, 64, jnp.float32),
                           (64, 128, 32, 128, jnp.bfloat16),
                           (7, 9, 8, 8, jnp.float32)])
-def test_omp_gram_matches_oracle(n, D, ti, td, dtype):
+def test_omp_gram_matches_oracle(n, D, tn, td, dtype):
     from repro.kernels.omp_gram.kernel import omp_gram
     from repro.kernels.omp_gram.ref import omp_gram_ref
     g = _arr((n, D), dtype)
-    got = omp_gram(g, ti=ti, tj=ti, td=td, interpret=True)
+    got = omp_gram(g, tn=tn, td=td, interpret=True)
     want = omp_gram_ref(g)
     tol = 1e-3 if dtype == jnp.float32 else 1e-1
     assert jnp.allclose(got, want, atol=tol), float(jnp.abs(got - want).max())

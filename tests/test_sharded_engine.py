@@ -278,6 +278,7 @@ def test_sharded_epoch_matches_single_device_lm():
     covers cross-device reduction reordering at loss scale ~15."""
     out = _run(textwrap.dedent("""
         import numpy as np, jax
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config
         from repro.configs.base import PGMConfig, TrainConfig
         from repro.data.pipeline import lm_units
@@ -296,7 +297,7 @@ def test_sharded_epoch_matches_single_device_lm():
                                        sketch_dim_h=24, sketch_dim_v=24))
         h1 = train_with_selection(m, units, tc, method="pgm",
                                   val_units=val, engine="scan")
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         h2 = train_with_selection(m, units, tc, method="pgm",
                                   val_units=val, engine="scan", mesh=mesh)
         assert np.allclose(h1.train_loss, h2.train_loss,
@@ -321,6 +322,7 @@ def test_sharded_epoch_matches_single_device_lm():
 def test_sharded_epoch_matches_single_device_rnnt():
     out = _run(textwrap.dedent("""
         import numpy as np, jax
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config
         from repro.configs.base import PGMConfig, TrainConfig
         from repro.data.pipeline import asr_units
@@ -342,7 +344,7 @@ def test_sharded_epoch_matches_single_device_rnnt():
                                        val_matching=True))
         h1 = train_with_selection(m, units, tc, method="pgm",
                                   val_units=val, engine="scan")
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         h2 = train_with_selection(m, units, tc, method="pgm",
                                   val_units=val, engine="scan", mesh=mesh,
                                   epoch_chunk=2)
@@ -364,6 +366,7 @@ def test_sharded_chunked_path_compiles_one_epoch_executable():
     chunked executable (the full warm-start chunk has its own)."""
     out = _run(textwrap.dedent("""
         import numpy as np, jax
+        from repro.launch.mesh import make_mesh
         from repro.analysis.contracts import assert_retrace_free
         from repro.configs import get_config
         from repro.configs.base import PGMConfig, TrainConfig
@@ -378,7 +381,7 @@ def test_sharded_chunked_path_compiles_one_epoch_executable():
                                         hard_fraction=0.4), 4)
         tc = TrainConfig(lr=0.5, optimizer="sgd", epochs=1,
                          pgm=PGMConfig())
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         eng = EpochEngine(m, tc, units, batch_units=1, mesh=mesh)
         assert eng.steps_per_epoch_max == 32 and eng.plan_granule == 4
         opt_init, _ = make_update_for(tc)

@@ -30,9 +30,10 @@ def _run(code: str) -> str:
 def test_spec_builder_rules():
     out = _run(textwrap.dedent("""
         import jax
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
         from repro.sharding.specs import SpecBuilder
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         sb = SpecBuilder(mesh)
         # wq: (d, q_dim) -> (fsdp, tp)
         assert sb.param_spec(".blocks.attn.wq", (64, 64)) == P("data", "model")
@@ -63,9 +64,10 @@ def test_spec_builder_expert_mode():
     names the arch, and the pod axis never leaks into param specs."""
     out = _run(textwrap.dedent("""
         import jax
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
         from repro.sharding.specs import SpecBuilder
-        mesh = jax.make_mesh((2, 2, 2), ("data", "expert", "model"))
+        mesh = make_mesh((2, 2, 2), ("data", "expert", "model"))
         sb = SpecBuilder(mesh, mode="expert", arch="mixtral-8x7b")
         # stacked expert banks: E over 'expert', d over fsdp, dff over tp
         s = sb.param_spec(".groups.moe.w_in", (2, 4, 64, 32))
@@ -85,12 +87,12 @@ def test_spec_builder_expert_mode():
             assert "mixtral-8x7b" in str(e) and "expert" in str(e), e
         # no expert axis on the mesh: E falls back to the fsdp axis and
         # the d dim is left alone (never shard one axis twice)
-        mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+        mesh2 = make_mesh((4, 2), ("data", "model"))
         sb2 = SpecBuilder(mesh2, mode="expert", arch="olmoe-1b-7b")
         s = sb2.param_spec(".moe.w_in", (4, 64, 32))
         assert s == P("data", None, "model"), s
         # the pod axis is excluded from both fsdp and expert fallback
-        mesh3 = jax.make_mesh((2, 2, 2), ("data", "expert", "pod"))
+        mesh3 = make_mesh((2, 2, 2), ("data", "expert", "pod"))
         sb3 = SpecBuilder(mesh3, mode="expert", pod_axis="pod", arch="x")
         for name, shape in ((".moe.w_in", (2, 64, 32)),
                             (".blocks.attn.wq", (64, 64)),
@@ -110,6 +112,7 @@ def test_expert_specs_round_trip_shard_state_restore():
     restore cannot silently change the expert placement."""
     out = _run(textwrap.dedent("""
         import numpy as np, jax
+        from repro.launch.mesh import make_mesh
         import jax.tree_util as jtu
         from repro.configs import get_config
         from repro.configs.base import PGMConfig, TrainConfig
@@ -123,7 +126,7 @@ def test_expert_specs_round_trip_shard_state_restore():
         units = lm_units(make_lm_corpus(0, 8, 10, cfg.vocab_size), 2)
         tc = TrainConfig(lr=0.2, optimizer="sgd", epochs=1,
                          pgm=PGMConfig())
-        mesh = jax.make_mesh((2, 2), ("data", "expert"))
+        mesh = make_mesh((2, 2), ("data", "expert"))
         eng = EpochEngine(m, tc, units, batch_units=2, mesh=mesh,
                           spec_mode="expert")
         opt_init, _ = make_update_for(tc)
@@ -147,9 +150,10 @@ def test_expert_specs_round_trip_shard_state_restore():
 def test_pgm_stage_b_shard_map_matches_single_device():
     out = _run(textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs.base import PGMConfig
         from repro.core.pgm import partitioned_gm, pgm_select_sharded
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         rng = np.random.default_rng(0)
         g = jnp.asarray(rng.normal(size=(32, 16)), jnp.float32)
         pc = PGMConfig(subset_fraction=0.25, n_partitions=8)
@@ -167,17 +171,17 @@ def test_pgm_stage_b_shard_map_matches_single_device():
 def test_compressed_psum_modes():
     out = _run(textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
         from repro.train.compress import compressed_psum, init_error_state
-        mesh = jax.make_mesh((8,), ("pod",))
+        mesh = make_mesh((8,), ("pod",))
         g = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
         err = init_error_state({"w": jnp.zeros((8,))})
         def f(gl):
             red, _ = compressed_psum(gl, "pod", mode="bf16")
             return red
-        out = shard_map(f, mesh=mesh, in_specs=({"w": P("pod")},),
-                        out_specs={"w": P("pod")})(g)
+        out = jax.shard_map(f, mesh=mesh, in_specs=({"w": P("pod")},),
+                            out_specs={"w": P("pod")})(g)
         # the collective reduces AT bf16 width (cast before the pmean, so
         # the wire moves half the bytes): mean computed in bf16, then
         # upcast
@@ -200,12 +204,13 @@ def test_reduced_train_step_compiles_on_mesh(arch, policy):
     (2,4) mesh — fast proxy for the 512-device dry-run cells."""
     out = _run(textwrap.dedent(f"""
         import jax
+        from repro.launch.mesh import make_mesh
         import repro.launch.dryrun as dr
         import repro.configs as C
         orig = C.get_config
         dr.get_config = lambda name: orig(name + "-smoke")
         import repro.launch.roofline as rf
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         fn, args = dr.build_step({arch!r}, "train_4k", mesh,
                                  policy={policy!r})
         compiled = fn.lower(*args).compile()
@@ -218,11 +223,12 @@ def test_reduced_train_step_compiles_on_mesh(arch, policy):
 def test_decode_step_compiles_on_mesh():
     out = _run(textwrap.dedent("""
         import jax
+        from repro.launch.mesh import make_mesh
         import repro.launch.dryrun as dr
         import repro.configs as C
         orig = C.get_config
         dr.get_config = lambda name: orig(name + "-smoke")
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         fn, args = dr.build_step("gemma3-27b", "decode_32k", mesh)
         compiled = fn.lower(*args).compile()
         print("DECODE-COMPILE-OK")

@@ -36,6 +36,7 @@ import jax                                                  # noqa: E402
 import jax.numpy as jnp                                     # noqa: E402
 import numpy as np                                          # noqa: E402
 
+from repro import obs                                       # noqa: E402
 from repro.configs import get_config                        # noqa: E402
 from repro.configs.base import PGMConfig, TrainConfig       # noqa: E402
 from repro.data.pipeline import asr_units                   # noqa: E402
@@ -119,16 +120,10 @@ def _train_config(spec: Spec) -> TrainConfig:
                       warm_start_epochs=spec.warm_start))
 
 
-class CompileClock:
-    """Sums JAX's backend-compile durations while registered."""
-
-    def __init__(self):
-        self.seconds = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self._on)
-
-    def _on(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
+def _compiles() -> str:
+    """JAX's backend compiles in this process (``repro.obs``)."""
+    return (f"{obs.value('compile.count')} compiles, "
+            f"{obs.value('compile.seconds')!r} s")
 
 
 def train(spec: Spec, *, mesh=None, log=print) -> dict:
@@ -261,8 +256,7 @@ def _memory(ma) -> dict:
             ("argument", "output", "temp", "generated_code")}
 
 
-def one_chip(spec: Spec, clock: CompileClock, *, interpret: bool = False,
-             log=print) -> dict:
+def one_chip(spec: Spec, *, interpret: bool = False, log=print) -> dict:
     """Train once and compare kernels and loss with their references;
     returns what only the platform can decide (kernel backends)."""
     prog = epoch_program(spec)
@@ -274,7 +268,7 @@ def one_chip(spec: Spec, clock: CompileClock, *, interpret: bool = False,
         log(f"epoch {i}: train {tl!r} val {vl!r}")
     log(f"selected {run['selected_units']} of {run['n_units']} units on "
         f"{run['selection_kernels']} selection kernels")
-    log(f"train wall {run['wall_s']!r} s, compile {clock.seconds!r} s, "
+    log(f"train wall {run['wall_s']!r} s, {_compiles()}, "
         f"peak bytes {_peak_bytes()}")
     kern = compare_kernels(spec, interpret=interpret)
     log(f"lattice {kern['lattice_shape']} vs ref: {kern['lattice_err']!r} "
@@ -292,7 +286,7 @@ def one_chip(spec: Spec, clock: CompileClock, *, interpret: bool = False,
             "selection_kernels": [run["selection_kernels"]]}
 
 
-def four_chips(spec: Spec, clock: CompileClock, *, log=print) -> dict:
+def four_chips(spec: Spec, *, log=print) -> dict:
     """The same run on a 4x1 data mesh and on one device, compared."""
     mesh = make_mesh((4, 1), ("data", "model"), devices=jax.devices()[:4])
     prog = epoch_program(spec, mesh=mesh)
@@ -319,8 +313,8 @@ def four_chips(spec: Spec, clock: CompileClock, *, log=print) -> dict:
         f"{sharded['selected_units']} units, same indices "
         f"{single['indices'] == sharded['indices']}")
     tol = MESH_TOL[jax.devices()[0].platform]
-    log(f"worst relative loss gap {worst!r} (tol {tol}); compile "
-        f"{clock.seconds!r} s, peak bytes on device 0 {_peak_bytes()}")
+    log(f"worst relative loss gap {worst!r} (tol {tol}); {_compiles()}, "
+        f"peak bytes on device 0 {_peak_bytes()}")
     require(worst <= tol, "4x1 mesh losses off the one-device run")
     return {"custom_call": prog["custom_call"],
             "selection_kernels": [single["selection_kernels"],
@@ -345,8 +339,7 @@ def main(argv=None) -> int:
         return 2
     print(f"device: {dev.device_kind} x{len(devices)}; compile cache "
           f"{enable_compile_cache()}")
-    clock = CompileClock()
-    facts = (four_chips if args.four_chips else one_chip)(Spec(), clock)
+    facts = (four_chips if args.four_chips else one_chip)(Spec())
     require(facts["custom_call"], "no Pallas kernel in the compiled epoch")
     require(all(k == "pallas" for k in facts["selection_kernels"]),
             f"selection ran on {facts['selection_kernels']}, not pallas")

@@ -32,6 +32,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import gm
 from repro.core.lastlayer import units_gradients, units_gradients_batched
 from repro.core.sketch import Projections
@@ -174,8 +175,8 @@ def _soft_random_selection(key, n_units: int, pgm_cfg) -> Selection:
     random subset at the configured budget with unit weights — the same
     Selection convention as ``baselines.random_subset`` (inlined here
     because baselines imports this module).  Training proceeds on a
-    defensible subset instead of dying mid-run (DESIGN.md §10);
-    ``ResidentSelector.degraded_rounds`` counts how often."""
+    defensible subset instead of dying mid-run (DESIGN.md §10); the
+    ``select.degraded_rounds`` counter (``repro.obs``) counts how often."""
     budget = max(int(pgm_cfg.subset_fraction * n_units), 1)
     idx = jax.random.permutation(key, n_units)[:budget].astype(jnp.int32)
     return Selection(idx, jnp.ones((budget,), jnp.float32),
@@ -204,7 +205,9 @@ class ResidentSelector:
     bit-identical XLA path — both stage A (re-jitted) and stage B read
     the updated ``kernel_impl`` — and if the scorer still fails the
     round degrades to a soft-random subset rather than killing a
-    multi-epoch run.
+    multi-epoch run.  The backend in use is the ``select.kernel_impl``
+    label of ``repro.obs``; ``select.fallbacks`` counts the Pallas -> XLA
+    fallbacks and ``select.degraded_rounds`` the soft-random rounds.
 
     Usage (see ``train/loop.py``)::
 
@@ -238,8 +241,7 @@ class ResidentSelector:
         # data-dependent (TPU vs host), and a silent wrong backend is
         # exactly the kind of perf bug a log line catches
         self.kernel_impl = resolve_kernel_impl(impl)
-        self._fell_back = False
-        self.degraded_rounds = 0
+        obs.note("select.kernel_impl", self.kernel_impl)
         self._round = 0
         if log_fn is not None:
             log_fn(f"selection kernels: requested={impl} "
@@ -283,12 +285,13 @@ class ResidentSelector:
         try:
             return self._select_round(params, units, val_units)
         except Exception as err:
-            if self.kernel_impl == "pallas" and not self._fell_back:
-                self._fell_back = True
+            if self.kernel_impl == "pallas":
                 self._log(f"warning: Pallas selection round failed "
                           f"({err}); falling back to the bit-identical "
                           f"XLA path for all remaining rounds")
                 self.kernel_impl = "xla"
+                obs.note("select.kernel_impl", "xla")
+                obs.count("select.fallbacks")
                 self.cfg = dataclasses.replace(self.cfg,
                                                kernel_impl="xla")
                 self._build_stage_a("xla")
@@ -296,7 +299,7 @@ class ResidentSelector:
                     return self._select_round(params, units, val_units)
                 except Exception as err2:
                     err = err2
-            self.degraded_rounds += 1
+            obs.count("select.degraded_rounds")
             n_units = jax.tree.leaves(units)[0].shape[0]
             self._log(f"warning: selection scorer failed ({err}); "
                       f"degrading this round to a soft-random subset")

@@ -233,29 +233,30 @@ def _alpha_inputs(lpb, lpe):
 def _fused_forward(blank, vocab_chunk, impl, part, ze, zp, w_out,
                    labels, t_lens, u_lens):
     """Stream the joint over T rows -> (nll, lpb, lpe, logz, alphas)."""
-    B, T, J = ze.shape
-    U1 = zp.shape[1]
-    wp, valid = _vocab_chunks(w_out, vocab_chunk)
-    w_blank = w_out[:, blank]
-    lab = jnp.pad(labels, ((0, 0), (0, 1))).astype(jnp.int32)   # (B,U1)
-    w_lab = w_out.T[lab]                                        # (B,U1,J)
-    emit_valid = jnp.arange(U1)[None, :] < u_lens[:, None]
+    with jax.named_scope("rnnt_loss.fwd"):
+        B, T, J = ze.shape
+        U1 = zp.shape[1]
+        wp, valid = _vocab_chunks(w_out, vocab_chunk)
+        w_blank = w_out[:, blank]
+        lab = jnp.pad(labels, ((0, 0), (0, 1))).astype(jnp.int32)   # (B,U1)
+        w_lab = w_out.T[lab]                                        # (B,U1,J)
+        emit_valid = jnp.arange(U1)[None, :] < u_lens[:, None]
 
-    def row(_, ze_t):
-        z = jnp.tanh(ze_t[:, None, :] + zp)                     # (B,U1,J)
-        return None, _row_scores(z, wp, valid, w_blank, w_lab, emit_valid)
+        def row(_, ze_t):
+            z = jnp.tanh(ze_t[:, None, :] + zp)                     # (B,U1,J)
+            return None, _row_scores(z, wp, valid, w_blank, w_lab, emit_valid)
 
-    _, (lpb, lpe, logz) = jax.lax.scan(row, None, jnp.moveaxis(ze, 1, 0))
+        _, (lpb, lpe, logz) = jax.lax.scan(row, None, jnp.moveaxis(ze, 1, 0))
 
-    alphas = _lattice(*_alpha_inputs(lpb, lpe), impl, part)     # (T,B,U1)
-    t_idx = jnp.clip(t_lens - 1, 0, T - 1)
-    bidx = jnp.arange(B)
-    a_final = alphas[t_idx, bidx]                               # (B,U1)
-    a_at_u = jnp.take_along_axis(a_final, u_lens[:, None], axis=1)[:, 0]
-    b_final = jnp.take_along_axis(lpb[t_idx, bidx], u_lens[:, None],
-                                  axis=1)[:, 0]
-    nll = -(a_at_u + b_final)
-    return nll, (lpb, lpe, logz, alphas)
+        alphas = _lattice(*_alpha_inputs(lpb, lpe), impl, part)     # (T,B,U1)
+        t_idx = jnp.clip(t_lens - 1, 0, T - 1)
+        bidx = jnp.arange(B)
+        a_final = alphas[t_idx, bidx]                               # (B,U1)
+        a_at_u = jnp.take_along_axis(a_final, u_lens[:, None], axis=1)[:, 0]
+        b_final = jnp.take_along_axis(lpb[t_idx, bidx], u_lens[:, None],
+                                      axis=1)[:, 0]
+        nll = -(a_at_u + b_final)
+        return nll, (lpb, lpe, logz, alphas)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
@@ -279,86 +280,87 @@ def _rnnt_fused_bwd(blank, vocab_chunk, impl, part, res, g):
     """Beta lattice + closed-form occupancy gradient, streamed over T rows
     and vocab chunks into (dze, dzp, dw_out) — the (B,T,U1,V) logits
     gradient is never materialized."""
-    (ze, zp, w_out, labels, t_lens, u_lens,
-     lpb, lpe, logz, alphas, nll) = res
-    B, T, J = ze.shape
-    U1 = zp.shape[1]
-    V = w_out.shape[1]
+    with jax.named_scope("rnnt_loss.bwd"):
+        (ze, zp, w_out, labels, t_lens, u_lens,
+         lpb, lpe, logz, alphas, nll) = res
+        B, T, J = ze.shape
+        U1 = zp.shape[1]
+        V = w_out.shape[1]
 
-    # --- beta lattice: same recurrence on (t, u)-flipped rows, with the
-    # terminal blank injected through the additive term ---------------------
-    t_ids = jnp.arange(T)[:, None, None]
-    u_ids = jnp.arange(U1)[None, None, :]
-    terminal = ((t_ids == (t_lens - 1)[None, :, None])
-                & (u_ids == u_lens[None, :, None]))             # (T,B,U1)
-    term = jnp.where(terminal, lpb, NEG)
-    flip = lambda x: x[::-1, :, ::-1]
-    betas = flip(_lattice(flip(lpb), flip(term), flip(lpe), impl, part))
+        # --- beta lattice: same recurrence on (t, u)-flipped rows, with the
+        # terminal blank injected through the additive term -----------------
+        t_ids = jnp.arange(T)[:, None, None]
+        u_ids = jnp.arange(U1)[None, None, :]
+        terminal = ((t_ids == (t_lens - 1)[None, :, None])
+                    & (u_ids == u_lens[None, :, None]))             # (T,B,U1)
+        term = jnp.where(terminal, lpb, NEG)
+        flip = lambda x: x[::-1, :, ::-1]
+        betas = flip(_lattice(flip(lpb), flip(term), flip(lpe), impl, part))
 
-    # --- arc posteriors ----------------------------------------------------
-    logp = -nll                                                 # (B,)
-    neg_row = jnp.full((1, B, U1), NEG)
-    beta_next_t = jnp.concatenate([betas[1:], neg_row], axis=0)
-    beta_dest = jnp.logaddexp(beta_next_t, jnp.where(terminal, 0.0, NEG))
-    occ_b = jnp.exp(alphas + lpb + beta_dest - logp[None, :, None])
-    beta_next_u = jnp.pad(betas[:, :, 1:], ((0, 0), (0, 0), (0, 1)),
-                          constant_values=NEG)
-    occ_e = jnp.exp(alphas + lpe + beta_next_u - logp[None, :, None])
-    gamma = occ_b + occ_e                                       # (T,B,U1)
+        # --- arc posteriors ------------------------------------------------
+        logp = -nll                                                 # (B,)
+        neg_row = jnp.full((1, B, U1), NEG)
+        beta_next_t = jnp.concatenate([betas[1:], neg_row], axis=0)
+        beta_dest = jnp.logaddexp(beta_next_t, jnp.where(terminal, 0.0, NEG))
+        occ_b = jnp.exp(alphas + lpb + beta_dest - logp[None, :, None])
+        beta_next_u = jnp.pad(betas[:, :, 1:], ((0, 0), (0, 0), (0, 1)),
+                              constant_values=NEG)
+        occ_e = jnp.exp(alphas + lpe + beta_next_u - logp[None, :, None])
+        gamma = occ_b + occ_e                                       # (T,B,U1)
 
-    # --- stream d logits = p*gamma - occ_b*1_blank - occ_e*1_label into the
-    # factor gradients, row by row -----------------------------------------
-    wp, valid = _vocab_chunks(w_out, vocab_chunk)
-    nc, _, chunk = wp.shape
-    w_blank = w_out[:, blank]
-    lab = jnp.pad(labels, ((0, 0), (0, 1))).astype(jnp.int32)
-    w_lab = w_out.T[lab]                                        # (B,U1,J)
-    gB = g.astype(jnp.float32)                                  # (B,)
+        # --- stream d logits = p*gamma - occ_b*1_blank - occ_e*1_label into
+        # the factor gradients, row by row ----------------------------------
+        wp, valid = _vocab_chunks(w_out, vocab_chunk)
+        nc, _, chunk = wp.shape
+        w_blank = w_out[:, blank]
+        lab = jnp.pad(labels, ((0, 0), (0, 1))).astype(jnp.int32)
+        w_lab = w_out.T[lab]                                        # (B,U1,J)
+        gB = g.astype(jnp.float32)                                  # (B,)
 
-    def row(carry, xs):
-        dzp_acc, dwo, dwlab = carry
-        ze_t, gamma_t, occb_t, occe_t, logz_t = xs
-        z = jnp.tanh(ze_t[:, None, :] + zp)                     # (B,U1,J)
-        coef = gamma_t * gB[:, None]                            # (B,U1)
+        def row(carry, xs):
+            dzp_acc, dwo, dwlab = carry
+            ze_t, gamma_t, occb_t, occe_t, logz_t = xs
+            z = jnp.tanh(ze_t[:, None, :] + zp)                     # (B,U1,J)
+            coef = gamma_t * gB[:, None]                            # (B,U1)
 
-        def chunk_step(dz, xs2):
-            wc, vc = xs2
-            lg = jnp.einsum("buj,jc->buc", z, wc)
-            p = jnp.where(vc[None, None, :],
-                          jnp.exp(lg - logz_t[..., None]), 0.0)
-            pc = p * coef[..., None]                            # (B,U1,C)
-            dwo_c = jnp.einsum("buj,buc->jc", z, pc)
-            dz = dz + jnp.einsum("buc,jc->buj", pc, wc)
-            return dz, dwo_c
+            def chunk_step(dz, xs2):
+                wc, vc = xs2
+                lg = jnp.einsum("buj,jc->buc", z, wc)
+                p = jnp.where(vc[None, None, :],
+                              jnp.exp(lg - logz_t[..., None]), 0.0)
+                pc = p * coef[..., None]                            # (B,U1,C)
+                dwo_c = jnp.einsum("buj,buc->jc", z, pc)
+                dz = dz + jnp.einsum("buc,jc->buj", pc, wc)
+                return dz, dwo_c
 
-        dz, dwo_chunks = jax.lax.scan(
-            chunk_step, jnp.zeros((B, U1, J), jnp.float32), (wp, valid))
-        dwo = dwo + jnp.moveaxis(dwo_chunks, 0, 1).reshape(
-            J, nc * chunk)[:, :V]
-        cb = occb_t * gB[:, None]
-        ce = occe_t * gB[:, None]
-        dz = dz - cb[..., None] * w_blank - ce[..., None] * w_lab
-        dwo = dwo.at[:, blank].add(-jnp.einsum("bu,buj->j", cb, z))
-        dwlab = dwlab + ce[..., None] * z
-        dpre = dz * (1.0 - z * z)                               # tanh'
-        dzp_acc = dzp_acc + dpre
-        return (dzp_acc, dwo, dwlab), dpre.sum(axis=1)
+            dz, dwo_chunks = jax.lax.scan(
+                chunk_step, jnp.zeros((B, U1, J), jnp.float32), (wp, valid))
+            dwo = dwo + jnp.moveaxis(dwo_chunks, 0, 1).reshape(
+                J, nc * chunk)[:, :V]
+            cb = occb_t * gB[:, None]
+            ce = occe_t * gB[:, None]
+            dz = dz - cb[..., None] * w_blank - ce[..., None] * w_lab
+            dwo = dwo.at[:, blank].add(-jnp.einsum("bu,buj->j", cb, z))
+            dwlab = dwlab + ce[..., None] * z
+            dpre = dz * (1.0 - z * z)                               # tanh'
+            dzp_acc = dzp_acc + dpre
+            return (dzp_acc, dwo, dwlab), dpre.sum(axis=1)
 
-    carry0 = (jnp.zeros_like(zp, jnp.float32),
-              jnp.zeros((J, V), jnp.float32),
-              jnp.zeros((B, U1, J), jnp.float32))
-    (dzp, dwo, dwlab), dze_rows = jax.lax.scan(
-        row, carry0,
-        (jnp.moveaxis(ze, 1, 0), gamma, occ_b, occ_e, logz))
-    # scatter the accumulated -occ_e * z contributions at label columns
-    scatter = jnp.zeros((V, J), jnp.float32).at[lab.reshape(-1)].add(
-        dwlab.reshape(-1, J))
-    dwo = dwo - scatter.T
-    dze = jnp.moveaxis(dze_rows, 0, 1)                          # (B,T,J)
+        carry0 = (jnp.zeros_like(zp, jnp.float32),
+                  jnp.zeros((J, V), jnp.float32),
+                  jnp.zeros((B, U1, J), jnp.float32))
+        (dzp, dwo, dwlab), dze_rows = jax.lax.scan(
+            row, carry0,
+            (jnp.moveaxis(ze, 1, 0), gamma, occ_b, occ_e, logz))
+        # scatter the accumulated -occ_e * z contributions at label columns
+        scatter = jnp.zeros((V, J), jnp.float32).at[lab.reshape(-1)].add(
+            dwlab.reshape(-1, J))
+        dwo = dwo - scatter.T
+        dze = jnp.moveaxis(dze_rows, 0, 1)                          # (B,T,J)
 
-    f0 = lambda x: np.zeros(x.shape, jax.dtypes.float0)
-    return (dze.astype(ze.dtype), dzp.astype(zp.dtype),
-            dwo.astype(w_out.dtype), f0(labels), f0(t_lens), f0(u_lens))
+        f0 = lambda x: np.zeros(x.shape, jax.dtypes.float0)
+        return (dze.astype(ze.dtype), dzp.astype(zp.dtype),
+                dwo.astype(w_out.dtype), f0(labels), f0(t_lens), f0(u_lens))
 
 
 _rnnt_fused.defvjp(_rnnt_fused_fwd, _rnnt_fused_bwd)

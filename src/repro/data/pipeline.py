@@ -21,7 +21,7 @@ order by construction.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Iterator, Optional, Tuple
+from typing import Dict, Iterator, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -61,6 +61,34 @@ def unit_durations(units: Dict[str, np.ndarray]) -> np.ndarray:
     if "feat_lens" in units:
         return units["feat_lens"].sum(axis=1).astype(np.float32)
     return units["loss_mask"].sum(axis=(1, 2)).astype(np.float32)
+
+
+def padded_length(units) -> int:
+    """Per-example length the units are padded to, in ``unit_durations``'
+    measure: frames for ASR units, tokens for LM units."""
+    return int((units["feats"] if "feat_lens" in units
+                else units["loss_mask"]).shape[2])
+
+
+class PlanCounts(NamedTuple):
+    """What one epoch plan runs, counted on the host.  Positions are
+    per-example time steps (``unit_durations``' measure)."""
+    steps: int
+    live_steps: int
+    positions: int          # steps x batch units x unit size x padded length
+    live_positions: int     # the real lengths of the live units
+
+
+def plan_counts(plan_idx: np.ndarray, durations: np.ndarray,
+                unit_size: int, padded_len: int) -> PlanCounts:
+    """Count a ``(n_steps, batch_units)`` plan of unit ids (padding -1)
+    against the units' ``unit_durations``."""
+    live = plan_idx >= 0
+    steps, batch_units = plan_idx.shape
+    return PlanCounts(
+        steps, int(live.any(axis=1).sum()),
+        steps * batch_units * unit_size * padded_len,
+        int(np.asarray(durations, np.float64)[plan_idx[live]].sum()))
 
 
 # ---------------------------------------------------------------------------

@@ -41,6 +41,8 @@ import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Callable, Dict, Hashable
 
+from repro import obs
+
 
 class PlanPrefetcher:
     """Single-worker double buffer for plan construction.
@@ -106,13 +108,15 @@ class PlanPrefetcher:
         built synchronously.  A builder exception raised on the worker
         thread propagates here, to the consumer that asked for the key
         (the slot is freed first, so retrying falls back to a
-        synchronous ``build``)."""
+        synchronous ``build``).  The wait is the ``repro.prefetch.wait``
+        span (``repro.obs``)."""
         fut = self._pending.pop(key, None)
-        if fut is None:
-            self.misses += 1
-            return self._build_with_retries(build)
-        self.hits += 1
-        return fut.result()        # re-raises the worker's exception
+        with obs.span("prefetch.wait", hit=fut is not None):
+            if fut is None:
+                self.misses += 1
+                return self._build_with_retries(build)
+            self.hits += 1
+            return fut.result()    # re-raises the worker's exception
 
     def invalidate(self):
         """Drop every pending entry (cancelling what hasn't started):

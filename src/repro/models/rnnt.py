@@ -127,32 +127,36 @@ def init_params(cfg, key) -> Dict:
 def encode(params, cfg, feats):
     """feats: (B,T,F) -> (B, T//4, dnn_dim)."""
     r = cfg.rnnt
-    x = feats[..., None]                                  # (B,T,F,1)
-    for i in range(len(r.cnn_channels)):
-        w, b = params[f"conv{i}"]["w"], params[f"conv{i}"]["b"]
-        x = jax.lax.conv_general_dilated(
-            x, w.astype(x.dtype), window_strides=(2, 2), padding="SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
-        x = jax.nn.relu(x + b.astype(x.dtype))
-    B, T4, F4, C = x.shape
-    x = x.reshape(B, T4, F4 * C)
-    for i in range(r.lstm_layers):
-        f = lstm_scan(params[f"lstm{i}_f"], x)
-        bwd = lstm_scan(params[f"lstm{i}_b"], x, reverse=True)
-        x = jnp.concatenate([f, bwd], axis=-1)
-    x = jax.nn.relu(x @ params["dnn0"]["w"].astype(x.dtype)
-                    + params["dnn0"]["b"].astype(x.dtype))
-    x = jax.nn.relu(x @ params["dnn1"]["w"].astype(x.dtype)
-                    + params["dnn1"]["b"].astype(x.dtype))
+    with jax.named_scope("cnn"):
+        x = feats[..., None]                              # (B,T,F,1)
+        for i in range(len(r.cnn_channels)):
+            w, b = params[f"conv{i}"]["w"], params[f"conv{i}"]["b"]
+            x = jax.lax.conv_general_dilated(
+                x, w.astype(x.dtype), window_strides=(2, 2), padding="SAME",
+                dimension_numbers=("NHWC", "HWIO", "NHWC"))
+            x = jax.nn.relu(x + b.astype(x.dtype))
+        B, T4, F4, C = x.shape
+        x = x.reshape(B, T4, F4 * C)
+    with jax.named_scope("encoder_lstm"):
+        for i in range(r.lstm_layers):
+            f = lstm_scan(params[f"lstm{i}_f"], x)
+            bwd = lstm_scan(params[f"lstm{i}_b"], x, reverse=True)
+            x = jnp.concatenate([f, bwd], axis=-1)
+    with jax.named_scope("dnn"):
+        x = jax.nn.relu(x @ params["dnn0"]["w"].astype(x.dtype)
+                        + params["dnn0"]["b"].astype(x.dtype))
+        x = jax.nn.relu(x @ params["dnn1"]["w"].astype(x.dtype)
+                        + params["dnn1"]["b"].astype(x.dtype))
     return x
 
 
 def predict(params, cfg, tokens):
     """tokens: (B,U) -> (B, U+1, pred_hidden): position u conditions on
     tokens[<u]; position 0 is the blank-start state."""
-    emb = jnp.take(params["pred_embed"]["w"], tokens, axis=0)
-    emb = jnp.pad(emb, ((0, 0), (1, 0), (0, 0)))          # start token = 0
-    g, _ = gru_scan(params["pred_gru"], emb)
+    with jax.named_scope("pred_gru"):
+        emb = jnp.take(params["pred_embed"]["w"], tokens, axis=0)
+        emb = jnp.pad(emb, ((0, 0), (1, 0), (0, 0)))      # start token = 0
+        g, _ = gru_scan(params["pred_gru"], emb)
     return g
 
 
@@ -164,8 +168,9 @@ def joint_factors(params, cfg, feats, tokens):
     enc = encode(params, cfg, feats)
     pred = predict(params, cfg, tokens)
     dt = enc.dtype
-    ze = enc @ params["joint"]["w_enc"].astype(dt)        # (B,T,J)
-    zp = pred @ params["joint"]["w_pred"].astype(dt)      # (B,U1,J)
+    with jax.named_scope("joint_proj"):
+        ze = enc @ params["joint"]["w_enc"].astype(dt)    # (B,T,J)
+        zp = pred @ params["joint"]["w_pred"].astype(dt)  # (B,U1,J)
     return ze, zp
 
 

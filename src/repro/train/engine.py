@@ -45,6 +45,7 @@ actual XLA compilations rather than a per-function python side effect.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Dict, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -52,8 +53,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.configs.base import TrainConfig
-from repro.data.pipeline import epoch_plan, subset_epoch_plan
+from repro.data.pipeline import (PlanCounts, epoch_plan, padded_length,
+                                 plan_counts, subset_epoch_plan,
+                                 unit_durations)
 from repro.train.compress import compressed_psum, init_error_state
 from repro.train.optim import (clip_by_global_norm, gate_step,
                                make_update_for)
@@ -146,7 +150,8 @@ def make_step_core(bundle, cfg: TrainConfig, shard=None, pod=None):
 
             (l, metrics), grads = jax.value_and_grad(loss,
                                                      has_aux=True)(params)
-            grads, gnorm = clip_by_global_norm(grads, cfg.grad_clip)
+            with jax.named_scope("grad_clip"):
+                grads, gnorm = clip_by_global_norm(grads, cfg.grad_clip)
             if guard:
                 # the clip already paid for the global norm: any NaN/Inf
                 # in the raw grads poisons the sum-of-squares, so one
@@ -157,8 +162,9 @@ def make_step_core(bundle, cfg: TrainConfig, shard=None, pod=None):
                 ok = finite if step_on is None else step_on & finite
             else:
                 ok = step_on
-            params, opt_state = opt_update(params, grads, opt_state, lr,
-                                           step_on=ok)
+            with jax.named_scope("optimizer"):
+                params, opt_state = opt_update(params, grads, opt_state, lr,
+                                               step_on=ok)
             metrics = dict(metrics, grad_norm=gnorm)
             if ok is not None:
                 metrics = {k: jnp.where(ok, v, jnp.zeros_like(v))
@@ -214,7 +220,8 @@ def make_step_core(bundle, cfg: TrainConfig, shard=None, pod=None):
         grads, new_err, metrics = jax.vmap(
             per_pod, in_axes=(0, 0), out_axes=(None, 0, None),
             axis_name=pod.axis, spmd_axis_name=pod.axis)(bp, err)
-        grads, gnorm = clip_by_global_norm(grads, cfg.grad_clip)
+        with jax.named_scope("grad_clip"):
+            grads, gnorm = clip_by_global_norm(grads, cfg.grad_clip)
         if guard:
             # the check runs on the post-collective gradients: a NaN/Inf
             # in any pod poisons the psum, so every pod gates off the
@@ -223,8 +230,9 @@ def make_step_core(bundle, cfg: TrainConfig, shard=None, pod=None):
             ok = finite if step_on is None else step_on & finite
         else:
             ok = step_on
-        params, opt_state = opt_update(params, grads, opt_state, lr,
-                                       step_on=ok)
+        with jax.named_scope("optimizer"):
+            params, opt_state = opt_update(params, grads, opt_state, lr,
+                                           step_on=ok)
         metrics = dict(metrics, grad_norm=gnorm)
         if ok is not None:
             # padding/guarded batches advance nothing: the error-feedback
@@ -256,6 +264,37 @@ def plan_live_steps(plan) -> np.ndarray:
     """Host-side mask of real (non-padding) steps in a plan — use it to
     exclude padding rows from per-step metric aggregates."""
     return np.asarray(plan[0])[:, 0] >= 0
+
+
+class Plan(tuple):
+    """``(batch_idx, batch_w)`` as ``EpochEngine`` builds them, with
+    ``counts``: the ``PlanCounts`` the host took from its own copy of the
+    plan while building it, so a dispatch can record them without a
+    device-to-host transfer."""
+
+    def __new__(cls, idx, w, counts: PlanCounts):
+        plan = super().__new__(cls, (idx, w))
+        plan.counts = counts
+        return plan
+
+
+def _counts_of(plans) -> Optional[PlanCounts]:
+    """The plans' counts summed, or None where one carries none."""
+    counts = [getattr(p, "counts", None) for p in plans]
+    if any(c is None for c in counts):
+        return None
+    return PlanCounts(*map(sum, zip(*counts)))
+
+
+def _abstract(tree):
+    """Shapes of a dispatch's arguments as the jit cache keys them (a
+    sharding only where an array is committed to one), so a lowering from
+    them finds the executable the dispatch compiled."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=a.sharding if a.committed else None,
+            weak_type=a.weak_type) if isinstance(a, jax.Array) else a,
+        tree)
 
 
 def autotune_loss_vocab_chunk(bundle, units, batch_units: int):
@@ -425,6 +464,13 @@ class EpochEngine:
                           else self._place_units(val_units))
         self.n_units = int(jax.tree.leaves(self.units)[0].shape[0])
         self.unit_size = int(jax.tree.leaves(self.units)[0].shape[1])
+        #: host copies for the plans' counts: per-unit real lengths and
+        #: the per-example padded length (``PlanCounts``)
+        self.unit_lens = np.asarray(unit_durations(units), np.float64)
+        self.padded_len = padded_length(units)
+        #: scope-map key (``repro.obs``) of each executable compiled, by
+        #: (jitted function, plan shape)
+        self._modules: Dict[Any, str] = {}
         #: full-data step count (upper bound for every plan shape)
         self.steps_per_epoch_max = self.n_units // self.batch_units
         #: bucket granule for padded subset plans (1/8 of a full epoch)
@@ -464,10 +510,11 @@ class EpochEngine:
                 # plan rows are wholly real or wholly padding; padding
                 # rows carry id -1 / weight 0 and must be bit-exact no-ops
                 live = idx[0] >= 0
-                batch = self.gather_batch(units, idx)
-                if "weights" in batch:
-                    batch = dict(batch, weights=batch["weights"]
-                                 * jnp.repeat(w, unit_size))
+                with jax.named_scope("batch_gather"):
+                    batch = self.gather_batch(units, idx)
+                    if "weights" in batch:
+                        batch = dict(batch, weights=batch["weights"]
+                                     * jnp.repeat(w, unit_size))
                 if pod is None:
                     p, s, metrics = step_core(p, s, batch, lr, step_on=live)
                     carry = (p, s)
@@ -750,14 +797,16 @@ class EpochEngine:
         return NamedSharding(self.mesh,
                              self.spec.param_spec(path, np.shape(arr)))
 
-    def _put_plan(self, idx, w):
+    def _put_plan(self, idx, w) -> Plan:
+        counts = plan_counts(idx, self.unit_lens, self.unit_size,
+                             self.padded_len)
         idx, w = jnp.asarray(idx), jnp.asarray(w)
         if self.mesh is not None and \
                 idx.shape[-1] % self.mesh.shape[self.data_axis] == 0:
             spec = P(*([None] * (idx.ndim - 1)), self.data_axis)
             sh = NamedSharding(self.mesh, spec)
             idx, w = jax.device_put(idx, sh), jax.device_put(w, sh)
-        return idx, w
+        return Plan(idx, w, counts)
 
     # ------------------------------------------------------------------
     def _plan_seed(self) -> int:
@@ -767,13 +816,16 @@ class EpochEngine:
         doesn't march through the same poisoned sequence."""
         return self.cfg.seed + 1_000_003 * self.plan_salt
 
-    def full_plan(self, epoch: int) -> Tuple[jax.Array, jax.Array]:
+    def full_plan(self, epoch: int) -> Plan:
         """(seed, epoch)-keyed full-data plan; unit weights are 1.  Shape
         ``(steps_per_epoch_max, batch_units)`` — identical to padded
         subset plans, so full and subset epochs share one executable."""
-        idx = epoch_plan(self.n_units, self._plan_seed(), epoch,
-                         self.batch_units)
-        return self._put_plan(idx, np.ones(idx.shape, np.float32))
+        with obs.span("plan.build") as sp:
+            idx = epoch_plan(self.n_units, self._plan_seed(), epoch,
+                             self.batch_units)
+            plan = self._put_plan(idx, np.ones(idx.shape, np.float32))
+            sp.set_metadata(**plan.counts._asdict())
+        return plan
 
     def bucket_steps(self, n_live_steps: int) -> int:
         """Round a live step count up to the next ``plan_granule``
@@ -789,8 +841,7 @@ class EpochEngine:
                    self.steps_per_epoch_max)
 
     def subset_plan(self, indices, weights, epoch: int,
-                    pad_to_steps: Optional[int] = None,
-                    ) -> Tuple[jax.Array, jax.Array]:
+                    pad_to_steps: Optional[int] = None) -> Plan:
         """(seed, epoch)-keyed weighted-subset plan.
 
         By default the plan is padded with weight-0 rows to
@@ -799,14 +850,18 @@ class EpochEngine:
         subset epoch still runs only ~``n_selected`` steps' worth of
         compute (pass ``pad_to_steps=0`` for the legacy unpadded shape,
         or any explicit step count)."""
-        if pad_to_steps is None:
-            n_live = int((np.asarray(indices) >= 0).sum())
-            pad_to_steps = self.bucket_steps(n_live // self.batch_units)
-        idx, w = subset_epoch_plan(np.asarray(indices), np.asarray(weights),
-                                   self._plan_seed(), epoch,
-                                   self.batch_units,
-                                   pad_to_steps=pad_to_steps or None)
-        return self._put_plan(idx, w)
+        with obs.span("plan.build") as sp:
+            if pad_to_steps is None:
+                n_live = int((np.asarray(indices) >= 0).sum())
+                pad_to_steps = self.bucket_steps(n_live // self.batch_units)
+            idx, w = subset_epoch_plan(np.asarray(indices),
+                                       np.asarray(weights),
+                                       self._plan_seed(), epoch,
+                                       self.batch_units,
+                                       pad_to_steps=pad_to_steps or None)
+            plan = self._put_plan(idx, w)
+            sp.set_metadata(**plan.counts._asdict())
+        return plan
 
     plan_live_steps = staticmethod(plan_live_steps)
 
@@ -827,13 +882,39 @@ class EpochEngine:
         docstring); in pod mode the engine-held ``compress_state`` is
         donated and replaced alongside them."""
         args = self._epoch_args(params, opt_state, lr, plan)
-        if self._pod is None:
-            params, opt_state, losses, skipped, nsk = self._run(*args)
-        else:
-            (params, opt_state, self.compress_state, losses, skipped,
-             nsk) = self._run(*args)
+        with self._dispatch(self._run, [plan], args):
+            if self._pod is None:
+                params, opt_state, losses, skipped, nsk = self._run(*args)
+            else:
+                (params, opt_state, self.compress_state, losses, skipped,
+                 nsk) = self._run(*args)
         self.last_skipped, self.last_n_skipped = skipped, nsk
         return params, opt_state, losses
+
+    @contextlib.contextmanager
+    def _dispatch(self, fn, plans, args):
+        """Around one dispatch of the jitted ``fn`` on ``plans``: the
+        ``repro.epoch.dispatch`` span and the dispatch record
+        (``repro.obs``), and the scope map of an executable the dispatch
+        compiled, lowered again from the arguments' shapes (taken before
+        the call donates them), which finds it in the cache and compiles
+        nothing.  The counts come from the plans' host copies: nothing
+        here waits for the device."""
+        counts = _counts_of(plans)
+        key = (fn, tuple(np.shape(plans[0][0])), len(plans))
+        shapes = None if key in self._modules else _abstract(args)
+        n_cached = fn._cache_size()
+        with obs.span("epoch.dispatch",
+                      **(counts._asdict() if counts else {})) as sp:
+            yield
+            compiled = fn._cache_size() > n_cached
+            sp.set_metadata(compiled=compiled)
+        if compiled and shapes is not None:
+            self._modules[key] = obs.register_module(
+                fn.lower(*shapes).compile().as_text())
+        obs.record_dispatch(obs.Dispatch(
+            self._modules.get(key), compiled,
+            *(counts if counts else (None,) * len(PlanCounts._fields))))
 
     def _epoch_args(self, params, opt_state, lr, plan):
         batch_idx, batch_w = plan
@@ -873,20 +954,19 @@ class EpochEngine:
         # stack preserves placement, so no second transfer is needed
         batch_idx = jnp.stack([p[0] for p in plans])
         batch_w = jnp.stack([p[1] for p in plans])
-        if self._pod is None:
-            (params, opt_state, losses, skipped, nsk, vls, lrs, lr_out,
-             prev_out) = self._run_chunk(params, opt_state, self.val_units,
-                                         batch_idx, batch_w,
-                                         jnp.asarray(lr, jnp.float32),
-                                         jnp.asarray(prev_loss, jnp.float32),
-                                         self.units)
-        else:
-            err = self._ensure_compress_state(params)
-            (params, opt_state, self.compress_state, losses, skipped, nsk,
-             vls, lrs, lr_out, prev_out) = self._run_chunk(
-                params, opt_state, err, self.val_units, batch_idx, batch_w,
-                jnp.asarray(lr, jnp.float32),
-                jnp.asarray(prev_loss, jnp.float32), self.units)
+        head = (params, opt_state)
+        if self._pod is not None:
+            head += (self._ensure_compress_state(params),)
+        args = head + (self.val_units, batch_idx, batch_w,
+                       jnp.asarray(lr, jnp.float32),
+                       jnp.asarray(prev_loss, jnp.float32), self.units)
+        with self._dispatch(self._run_chunk, plans, args):
+            if self._pod is None:
+                (params, opt_state, losses, skipped, nsk, vls, lrs, lr_out,
+                 prev_out) = self._run_chunk(*args)
+            else:
+                (params, opt_state, self.compress_state, losses, skipped,
+                 nsk, vls, lrs, lr_out, prev_out) = self._run_chunk(*args)
         self.last_skipped, self.last_n_skipped = skipped, nsk
         return params, opt_state, losses, vls, lrs, lr_out, prev_out
 

@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import TrainConfig
 from repro.core import baselines as bl
 from repro.core.lastlayer import make_proj_for, units_gradients
@@ -68,9 +69,11 @@ class History:
     skipped_steps: int = 0         # non-finite steps gated off on device
     rollbacks: int = 0             # divergence-watchdog restores
     preempted: bool = False        # exited early on SIGTERM/SIGINT
-    # resident selection rounds: the kernel backend they ran on and how
-    # many fell back to a soft-random subset (opt-in ladder only)
+    # resident selection rounds (``select.*`` in ``repro.obs``): the
+    # kernel backend they ran on, how many fell back from Pallas to XLA
+    # and how many to a soft-random subset (the opt-in ladder only)
     selection_kernels: Optional[str] = None
+    kernel_fallbacks: int = 0
     degraded_rounds: int = 0
 
 
@@ -163,6 +166,9 @@ def train_with_selection(
     durations = unit_durations({k: np.asarray(v) for k, v in units.items()})
     proj = make_proj_for(bundle, jax.random.fold_in(key, 17),
                          tc.pgm.sketch_dim_h, tc.pgm.sketch_dim_v)
+    # this run's share of the process-wide selection counters
+    fallbacks0 = obs.value("select.fallbacks")
+    degraded0 = obs.value("select.degraded_rounds")
     # resident rounds: stage A is one jitted batch-scanned pass over the
     # device-resident units (data-sharded with a mesh); the selector
     # caches its executable (and the projections, closed over the jit)
@@ -291,10 +297,11 @@ def train_with_selection(
             # --- selection round (the host sync point) ---
             if not use_full and (selection is None or _is_sel_epoch(epoch)):
                 sel_key = jax.random.fold_in(key, 1000 + epoch)
-                new_sel = _select(method, bundle, params, units_dev, tc,
-                                  sel_key, proj, val_dev, durations,
-                                  mesh=mesh, data_axis=data_axis,
-                                  resident=resident)
+                with obs.span("select.round", epoch=epoch):
+                    new_sel = _select(method, bundle, params, units_dev, tc,
+                                      sel_key, proj, val_dev, durations,
+                                      mesh=mesh, data_axis=data_axis,
+                                      resident=resident)
                 oi = (overlap_index(np.asarray(selection.indices),
                                     np.asarray(new_sel.indices))
                       if selection is not None else float("nan"))
@@ -474,10 +481,11 @@ def train_with_selection(
                     tree["err"] = (eng.compress_state
                                    if eng.compress_state is not None
                                    else eng.init_compress_state(params))
-                writer.submit(chunk_epochs[-1], tree, extra,
-                              mesh_shape=mesh_shape,
-                              compress_mode=(tc.compress_mode if pod_mode
-                                             else None))
+                with obs.span("ckpt.submit", epoch=chunk_epochs[-1]):
+                    writer.submit(chunk_epochs[-1], tree, extra,
+                                  mesh_shape=mesh_shape,
+                                  compress_mode=(tc.compress_mode if pod_mode
+                                                 else None))
             if preempted:
                 if writer is not None:
                     writer.wait()
@@ -501,6 +509,8 @@ def train_with_selection(
     hist.wall_time = time.time() - t0
     hist.final_params = params
     if resident is not None:
-        hist.selection_kernels = resident.kernel_impl
-        hist.degraded_rounds = resident.degraded_rounds
+        hist.selection_kernels = obs.value("select.kernel_impl")
+        hist.kernel_fallbacks = obs.value("select.fallbacks") - fallbacks0
+        hist.degraded_rounds = (obs.value("select.degraded_rounds")
+                                - degraded0)
     return hist

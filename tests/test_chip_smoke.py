@@ -19,14 +19,14 @@ from repro.launch.cache import DEFAULT_CACHE_DIR, enable_compile_cache  # noqa: 
 
 def test_one_chip_flow_at_smoke_size():
     lines = []
-    facts = chip_smoke.one_chip(chip_smoke.SMOKE, chip_smoke.CompileClock(),
-                                interpret=True, log=lines.append)
+    facts = chip_smoke.one_chip(chip_smoke.SMOKE, interpret=True,
+                                log=lines.append)
     # off the chip "auto" resolves to the XLA paths; main() refuses that
     assert facts == {"custom_call": False, "selection_kernels": ["xla"]}
     text = "\n".join(lines)
     for want in ("epoch 0: train", "epoch 2: train", "selected ",
                  "lattice (8, 8, 9) vs ref", "gram (4, 4, 4096) vs ref",
-                 "fused loss on cpu"):
+                 "fused loss on cpu", " compiles, "):
         assert want in text, (want, text)
 
 
@@ -44,7 +44,7 @@ def test_four_chip_flow_on_host_devices():
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
     code = ("import chip_smoke as cs\n"
-            "f = cs.four_chips(cs.SMOKE, cs.CompileClock())\n"
+            "f = cs.four_chips(cs.SMOKE)\n"
             "print('FACTS', f)\n")
     p = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env,
                        capture_output=True, text=True, timeout=600)

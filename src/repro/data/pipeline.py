@@ -127,11 +127,11 @@ def subset_epoch_plan(indices, weights, seed: int, epoch: int,
     exactly ``(pad_to_steps, batch_units)`` — id ``-1`` and weight ``0`` —
     so every selection round produces the same plan shape regardless of
     ``n_selected`` and one compiled epoch executable serves them all.
-    Padding-row semantics downstream (DESIGN.md §3): the engine clamps the
-    gather index to 0, runs the step, and gates the update with
-    ``optim.gate_step`` so a padding row advances neither params nor
-    optimizer state and contributes nothing to metrics.  Host iterators
-    never see padding rows (they call this with ``pad_to_steps=None``).
+    Padding-row semantics downstream (DESIGN.md §3): the engine skips a
+    padding row on the device (a ``lax.cond`` on its id), so it runs no
+    step, advances neither params nor optimizer state and contributes
+    nothing to metrics.  Host iterators never see padding rows (they call
+    this with ``pad_to_steps=None``).
     """
     valid = np.asarray(indices) >= 0
     idx = np.asarray(indices)[valid]

@@ -15,7 +15,8 @@ records and counters, each read by a named consumer (PERF.md §3).
   with the device planes; a microsecond or two when no profiler runs.
 * Dispatch records and counters: one ``Dispatch`` per epoch dispatch
   (the last ``MAX_DISPATCHES``), and named numbers and labels
-  (``select.*``; ``compile.count`` and ``compile.seconds``, JAX's
+  (``select.*``; ``epoch.gated_steps``, the padding rows the epoch
+  scans skipped; ``compile.count`` and ``compile.seconds``, JAX's
   backend compiles since import).
 
 The state is process-wide on purpose: a reader finds it after the

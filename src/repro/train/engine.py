@@ -32,12 +32,11 @@ Retrace-freedom (DESIGN.md §3): subset plans are padded with weight-0
 padding rows (unit id ``-1``) up to a *bucketed* step count — the next
 multiple of ``plan_granule`` (1/8 of the full-data step count) — so
 selection rounds whose ``n_selected`` lands in the same bucket reuse one
-compiled epoch executable, while a subset epoch still executes only
-~``n_selected/n_units`` of the full-epoch steps (padding waste is
-bounded by one granule, not by the subset fraction).  Padding rows are
-bit-exact no-ops: the gather index is clamped, the step runs, and
-``optim.gate_step`` selects the old ``(params, opt_state)`` leafwise, so
-the padded scan's state matches the unpadded loop's exactly.
+compiled epoch executable.  Padding rows are skipped on the device: the
+scan body runs the gather and the step under a ``lax.cond`` on the row's
+``idx[0] >= 0``, and a padding row passes the carry through untouched,
+so a subset epoch executes only its live steps and the padded scan's
+state matches the unpadded loop's exactly.
 Retrace-freedom is asserted by ``tests/test_resident_selection.py`` /
 ``tests/test_sharded_engine.py`` through the shared compile-counter
 contract (``repro.analysis.contracts.track_compiles``), which counts
@@ -80,13 +79,17 @@ class PodSpec(NamedTuple):
 def make_step_core(bundle, cfg: TrainConfig, shard=None, pod=None):
     """The un-jitted per-batch SGD update shared by the legacy host loop
     (which jits it per call) and the scanned engines (which embed it in
-    the scan body).
+    the scan body, on live plan rows only: a padding row runs no step).
 
-    ``step_on`` (optional traced bool scalar) is the padding-batch gate:
-    when False the optimizer update is a bit-exact no-op and every metric
-    is zeroed (no state advance, no metric contribution); when ``None``
-    (host loop — plans it consumes are never padded) no gating ops are
-    emitted.
+    ``step_on`` (optional traced bool scalar) gates the update: when
+    False the step is a bit-exact no-op (no state advance, every metric
+    zeroed); when ``None`` (the host loop) no gating ops are emitted.
+    The scanned epoch passes its row's ``idx[0] >= 0``, which is True on
+    every row that reaches the step.  The gate stays for the TPU
+    compiler's sake: without its selects XLA's memory-space assignment
+    leaves one of the encoder's bi-LSTM weight-gradient accumulators in
+    HBM instead of VMEM, ≈7.4 ms a step at ``rnnt-crdnn``'s widths
+    against ≈0.7 ms for the selects (PERF.md §5).
 
     The loss closure is whatever ``bundle.loss_fn`` resolves to from the
     model config — for RNN-T that is the fused custom_vjp transducer
@@ -114,8 +117,8 @@ def make_step_core(bundle, cfg: TrainConfig, shard=None, pod=None):
     example reduction stays a dense GSPMD mean-psum over ``data``.  The
     pod step's signature gains the per-pod error-feedback state:
     ``step(params, opt_state, batch, lr, err, step_on) ->
-    (params, opt_state, metrics, err)``; on gated-off padding steps the
-    error state is returned bit-identically (``optim.gate_step``).
+    (params, opt_state, metrics, err)``; on a gated-off step the error
+    state is returned bit-identically (``optim.gate_step``).
 
     Aux losses (e.g. the MoE router load-balance penalty) are computed
     per pod and pod-averaged — the standard data-parallel approximation
@@ -128,10 +131,10 @@ def make_step_core(bundle, cfg: TrainConfig, shard=None, pod=None):
     Non-finite guard (``cfg.nonfinite_guard``, DESIGN.md §10): the step
     additionally checks loss and (clipped) gradients for NaN/Inf in-jit
     and folds the result into the ``step_on`` gate — a poisoned batch
-    becomes a bit-exact no-op exactly like a weight-0 padding row (same
-    ``gate_step`` select, composing with pod-mode error-feedback
-    gating), its metrics are zeroed, and ``metrics["skipped"]`` reports
-    whether a *live* step was suppressed.  The check is trace-static:
+    becomes a bit-exact no-op, leaving the same state as a weight-0
+    padding row that the scan skips (the pod-mode error-feedback state
+    included), its metrics are zeroed, and ``metrics["skipped"]`` reports
+    whether a live step was suppressed.  The check is trace-static:
     guard on/off never retraces within a run, and a guarded run on
     all-finite data is bitwise identical to an unguarded one (the gate
     selects the new state everywhere).
@@ -235,7 +238,7 @@ def make_step_core(bundle, cfg: TrainConfig, shard=None, pod=None):
                                            step_on=ok)
         metrics = dict(metrics, grad_norm=gnorm)
         if ok is not None:
-            # padding/guarded batches advance nothing: the error-feedback
+            # a gated-off batch advances nothing: the error-feedback
             # state is selected back bit-exactly, like params/opt_state
             new_err = gate_step(ok, new_err, err)
             metrics = {k: jnp.where(ok, v, jnp.zeros_like(v))
@@ -375,8 +378,8 @@ class EpochEngine:
     standard multi-pod layout.  Top-k error-feedback residuals live in
     ``compress_state``: per-pod leaves ``(n_pods, *param_shape)`` sharded
     ``P(pod, *param_fsdp_spec)``, donated into every dispatch as part of
-    the scan carry, advanced not-at-all on weight-0 padding steps
-    (``optim.gate_step``), and checkpointed next to (params, opt_state)
+    the scan carry, passed through untouched on weight-0 padding rows,
+    and checkpointed next to (params, opt_state)
     so resume is bit-exact (``train/loop.py``).
 
     Plans: ``full_plan`` / ``subset_plan`` return ``(batch_idx, batch_w)``
@@ -388,8 +391,8 @@ class EpochEngine:
     are padded with id ``-1`` / weight ``0`` rows up to
     ``bucket_steps(live)`` — the next multiple of ``plan_granule`` — so
     rounds with a stable selection budget reuse one epoch executable
-    regardless of the exact ``n_selected``, at a padding overhead of at
-    most one granule (1/8 epoch).
+    regardless of the exact ``n_selected``; the padding rows, at most one
+    granule (1/8 epoch), run no step.
 
     Donation contract: inputs to ``run_epoch`` / ``run_epochs`` are
     donated — the caller must treat the passed-in ``params`` /
@@ -499,26 +502,24 @@ class EpochEngine:
         guard = self.guard
 
         def make_body(lr, units):
-            def body(carry, xs):
+            def train(carry, idx, w):
+                """One live plan row: the batch gather and the step."""
                 if guard:
                     *carry, nsk = carry
-                if pod is None:
-                    p, s = carry
-                else:
-                    p, s, err = carry
-                idx, w = xs
-                # plan rows are wholly real or wholly padding; padding
-                # rows carry id -1 / weight 0 and must be bit-exact no-ops
-                live = idx[0] >= 0
                 with jax.named_scope("batch_gather"):
                     batch = self.gather_batch(units, idx)
                     if "weights" in batch:
                         batch = dict(batch, weights=batch["weights"]
                                      * jnp.repeat(w, unit_size))
+                # True here; the gate is kept for the compiled memory
+                # placement it gives (make_step_core)
+                live = idx[0] >= 0
                 if pod is None:
+                    p, s = carry
                     p, s, metrics = step_core(p, s, batch, lr, step_on=live)
                     carry = (p, s)
                 else:
+                    p, s, err = carry
                     p, s, metrics, err = step_core(p, s, batch, lr, err,
                                                    step_on=live)
                     carry = (p, s, err)
@@ -531,6 +532,20 @@ class EpochEngine:
                 nsk = nsk + sk.astype(jnp.int32)
                 return carry + (nsk,), (metrics["loss"],
                                         sk.astype(jnp.float32))
+
+            def skip(carry, idx, w):
+                """One padding row: the carry passes through, and the
+                step reports a loss of 0, not skipped."""
+                zero = jnp.zeros((), jnp.float32)
+                return carry, (zero, zero) if guard else zero
+
+            def body(carry, xs):
+                # plan rows are wholly real or wholly padding (id -1,
+                # weight 0); a padding row runs no step.  The predicate
+                # is the scan's own, never batched by the pod vmap, so
+                # the cond stays a branch and not a select of both
+                idx, w = xs
+                return jax.lax.cond(idx[0] >= 0, train, skip, carry, idx, w)
 
             return body
 
@@ -691,11 +706,9 @@ class EpochEngine:
         return {k: place(jnp.asarray(v)) for k, v in units.items()}
 
     def gather_batch(self, units, idx):
-        """The step's batch for one plan row ``idx`` (padding ids -1 read
-        unit 0 and are gated off by weight), example axis data-sharded
-        on a mesh."""
-        gidx = jnp.maximum(idx, 0)
-        batch = {k: v[gidx].reshape((-1,) + v.shape[2:])
+        """The step's batch for one live plan row ``idx``, example axis
+        data-sharded on a mesh."""
+        batch = {k: v[idx].reshape((-1,) + v.shape[2:])
                  for k, v in units.items()}
         return self._constrain_batch(batch)
 
@@ -830,9 +843,9 @@ class EpochEngine:
     def bucket_steps(self, n_live_steps: int) -> int:
         """Round a live step count up to the next ``plan_granule``
         multiple (capped at ``steps_per_epoch_max``): the padded-plan
-        shape that bounds both recompiles (≤8 distinct buckets ever; one
-        in the common stable-budget case) and padding waste (≤1
-        granule).  Never returns 0 — a selection with fewer live units
+        shape that bounds recompiles (≤8 distinct buckets ever; one in
+        the common stable-budget case); its ≤1 granule of padding rows
+        runs no step.  Never returns 0 — a selection with fewer live units
         than a batch still yields a one-granule all-padding plan, keeping
         the shape inside the bucket family instead of tracing a fresh
         zero-length executable."""
@@ -846,10 +859,9 @@ class EpochEngine:
 
         By default the plan is padded with weight-0 rows to
         ``bucket_steps(live)`` so changing ``n_selected`` between
-        selection rounds reuses the compiled epoch executable while a
-        subset epoch still runs only ~``n_selected`` steps' worth of
-        compute (pass ``pad_to_steps=0`` for the legacy unpadded shape,
-        or any explicit step count)."""
+        selection rounds reuses the compiled epoch executable; the
+        padding rows run no step (pass ``pad_to_steps=0`` for the legacy
+        unpadded shape, or any explicit step count)."""
         with obs.span("plan.build") as sp:
             if pad_to_steps is None:
                 n_live = int((np.asarray(indices) >= 0).sum())
@@ -868,16 +880,15 @@ class EpochEngine:
     def epoch_cost(self, plan, use_full: bool = False,
                    n_selected: Optional[int] = None) -> float:
         """Full-epoch-equivalent compute charged for executing ``plan``:
-        the bucketed step count — padding rows run a full step before
-        being gated — so reported savings include the granule slack
-        honestly (DESIGN.md §3)."""
-        return plan[0].shape[0] / self.steps_per_epoch_max
+        its live steps, the only ones the device runs (DESIGN.md §3)."""
+        return int(plan_live_steps(plan).sum()) / self.steps_per_epoch_max
 
     def run_epoch(self, params, opt_state, lr,
                   plan: Tuple[jax.Array, jax.Array]):
         """One scanned epoch.  Returns ``(params, opt_state, losses)``
-        with ``losses`` of shape ``(n_steps,)`` — padding steps report 0
-        and must be masked out of aggregates with ``plan_live_steps``.
+        with ``losses`` of shape ``(n_steps,)`` — padding rows run no
+        step, report 0 and must be masked out of aggregates with
+        ``plan_live_steps``.
         The passed params/opt_state buffers are donated (see class
         docstring); in pod mode the engine-held ``compress_state`` is
         donated and replaced alongside them."""
@@ -894,7 +905,8 @@ class EpochEngine:
     @contextlib.contextmanager
     def _dispatch(self, fn, plans, args):
         """Around one dispatch of the jitted ``fn`` on ``plans``: the
-        ``repro.epoch.dispatch`` span and the dispatch record
+        ``repro.epoch.dispatch`` span, the ``epoch.gated_steps`` counter
+        (the padding rows the scan skips) and the dispatch record
         (``repro.obs``), and the scope map of an executable the dispatch
         compiled, lowered again from the arguments' shapes (taken before
         the call donates them), which finds it in the cache and compiles
@@ -904,8 +916,12 @@ class EpochEngine:
         key = (fn, tuple(np.shape(plans[0][0])), len(plans))
         shapes = None if key in self._modules else _abstract(args)
         n_cached = fn._cache_size()
-        with obs.span("epoch.dispatch",
-                      **(counts._asdict() if counts else {})) as sp:
+        span_args = {}
+        if counts:
+            span_args = dict(counts._asdict(),
+                             gated_steps=counts.steps - counts.live_steps)
+            obs.count("epoch.gated_steps", span_args["gated_steps"])
+        with obs.span("epoch.dispatch", **span_args) as sp:
             yield
             compiled = fn._cache_size() > n_cached
             sp.set_metadata(compiled=compiled)

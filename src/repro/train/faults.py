@@ -44,8 +44,8 @@ class FaultPlan:
     row into padding (ids -1, weight 0) instead — not a fault but the
     *reference* for the guard's documented semantics: a guarded-off
     non-finite step must be bit-identical to the run that trained the
-    same schedule with that batch as a padding row (the ``step_on``
-    gate).  ``prefetch_fail_epochs``
+    same schedule with that batch as a padding row, which the scan
+    skips.  ``prefetch_fail_epochs``
     raises from inside the plan builder the first time each listed
     epoch's plan is built.  ``preempt_after_epoch`` raises SIGTERM in
     the loop's own thread once that epoch's chunk completes.

@@ -26,9 +26,8 @@ def tree_all_finite(tree) -> jax.Array:
     leafwise sweep: gradient clipping already computes the global norm,
     and any NaN/Inf leaf poisons that sum of squares, so the in-scan
     guard checks ``isfinite(gnorm)`` — one scalar — and feeds it into
-    the same ``gate_step`` select that implements weight-0 padding
-    batches (a poisoned step advances nothing, bit-exactly, with no
-    host sync)."""
+    ``gate_step`` (a poisoned step advances nothing, bit-exactly, with
+    no host sync)."""
     leaves = jax.tree.leaves(tree)
     ok = jnp.bool_(True)
     for l in leaves:
@@ -112,15 +111,16 @@ def make_optimizer(name: str):
 
 
 def gate_step(step_on, new_tree, old_tree):
-    """Padding-aware step semantics: select ``new_tree`` where ``step_on``
-    (a traced boolean scalar) and ``old_tree`` otherwise, leafwise.
+    """The step gate (DESIGN.md §3, §10): select ``new_tree`` where
+    ``step_on`` (a traced boolean scalar) and ``old_tree`` otherwise,
+    leafwise.
 
-    A weight-0 padding batch (see ``data/pipeline.subset_epoch_plan``'s
-    ``pad_to_steps``) must advance *nothing*: no parameter update, no step
+    A gated-off step must advance *nothing*: no parameter update, no step
     counter tick, no Adam moment decay.  ``jnp.where`` on a scalar predicate
     lowers to a select, so a gated-off step returns the old buffers
-    bit-identically — padded and unpadded epochs produce the same
-    ``(params, opt_state)``.
+    bit-identically — the same state as a weight-0 padding row, which the
+    scanned epoch skips with a ``lax.cond`` before the step (its live
+    rows pass a gate that is True; ``engine.make_step_core`` says why).
     """
     return jax.tree.map(lambda a, b: jnp.where(step_on, a, b),
                         new_tree, old_tree)
@@ -131,11 +131,12 @@ def make_update_for(cfg):
     loop and the scanned epoch engine share one (init, update) pair:
     ``init(params) -> state``; ``update(params, grads, state, lr[, step_on])``.
 
-    ``step_on`` (optional traced bool scalar) implements the weight-0
-    padding-batch semantics of retrace-free subset plans: when False the
-    update is a bit-exact no-op for both params and optimizer state
-    (``gate_step``); when ``None`` (the host loop, real batches) no gating
-    ops are emitted at all.
+    ``step_on`` (optional traced bool scalar) is the step's gate (the
+    scanned epoch's row liveness and the non-finite guard): when False
+    the update is a bit-exact no-op for both params and optimizer state
+    (``gate_step``); when ``None`` (the host loop without the guard) no
+    gating ops are emitted at all.  Padding rows never reach the update:
+    the scanned epoch skips them.
     """
     init, update = make_optimizer(cfg.optimizer)
     kw = {"momentum": cfg.momentum} if cfg.optimizer == "sgd" else {}

@@ -80,20 +80,22 @@ def epochs(tmp_path_factory, no_persistent_cache):
     params = bundle.init_params(jax.random.PRNGKey(0))
     opt_state = opt_init(params)
     trace_dir = str(tmp_path_factory.mktemp("trace"))
-    compiles, records = [], []
+    compiles, gated, records = [], [], []
     with jax.profiler.trace(trace_dir):
         plan = eng.subset_plan(np.array([5, 1, 6, 2]), np.ones(4), 0,
                                pad_to_steps=4)
         for _ in range(2):
             before = obs.value("compile.count")
+            gated_before = obs.value("epoch.gated_steps")
             with no_implicit_transfers():
                 params, opt_state, _ = eng.run_epoch(params, opt_state,
                                                      0.01, plan)
             compiles.append(obs.value("compile.count") - before)
+            gated.append(obs.value("epoch.gated_steps") - gated_before)
             records.append(obs.dispatches()[-1])
     text = eng.lower_epoch(params, opt_state, 0.01, plan).compile().as_text()
     return {"eng": eng, "units": units, "plan": plan, "compiles": compiles,
-            "records": records, "text": text,
+            "gated": gated, "records": records, "text": text,
             "spans": _host_spans(trace_dir)}
 
 
@@ -140,6 +142,8 @@ def test_record_counts_come_from_the_plan(epochs):
         assert r.positions == 4 * 2 * 2 * T
         assert r.live_positions == want_live
     assert tuple(epochs["plan"].counts) == (4, 2, 4 * 2 * 2 * T, want_live)
+    # the padding rows the scan skips, counted per dispatch
+    assert epochs["gated"] == [2, 2]
 
 
 def test_spans_carry_their_counts(epochs):
@@ -148,7 +152,7 @@ def test_spans_carry_their_counts(epochs):
     builds = [a for n, a in spans if n == "repro.plan.build"]
     counts = dict(epochs["plan"].counts._asdict())
     assert [a.pop("compiled") for a in dispatches] == [1, 0]
-    assert dispatches == [counts, counts]
+    assert dispatches == [dict(counts, gated_steps=2)] * 2
     assert builds == [counts]
 
 

@@ -99,8 +99,9 @@ def test_subset_plan_padding_shape_and_sentinels():
 
 def test_bucketed_padding_bounds_subset_epoch_cost():
     """Padding must not erase the subset-compute saving: the padded plan
-    runs at most one granule (1/8 epoch) beyond the live steps, not the
-    full-data step count."""
+    is shaped at most one granule (1/8 epoch) beyond the live steps, not
+    the full-data step count, and its padding rows run no step, so the
+    epoch is charged its live steps alone."""
     m, units, tc, eng = _lm_engine(n_examples=128, batch_units=1)  # 32 units
     # never 0: an (almost-)empty selection stays in the bucket family
     assert [eng.bucket_steps(n) for n in (0, 1, 4, 5, 9, 31, 32)] == \
@@ -111,6 +112,7 @@ def test_bucketed_padding_bounds_subset_epoch_cost():
     assert n_steps == 12                          # not steps_per_epoch_max
     assert n_steps - 10 < eng.plan_granule
     assert int(eng.plan_live_steps(plan).sum()) == 10
+    assert eng.epoch_cost(plan) == pytest.approx(10 / 32)
     # a selection smaller than one batch still pads into the bucket family
     # (an all-padding one-granule plan, not a fresh zero-length executable)
     m2, units2, tc2, eng2 = _lm_engine()         # batch_units=2
@@ -118,6 +120,7 @@ def test_bucketed_padding_bounds_subset_epoch_cost():
                             np.ones(1, np.float32), epoch=0)
     assert tiny[0].shape == (eng2.plan_granule, eng2.batch_units)
     assert int(eng2.plan_live_steps(tiny).sum()) == 0
+    assert eng2.epoch_cost(tiny) == 0.0
     p = m2.init_params(jax.random.PRNGKey(0))
     opt_init2, _ = make_update_for(tc2)
     o = opt_init2(p)
@@ -131,10 +134,16 @@ def test_bucketed_padding_bounds_subset_epoch_cost():
 # Padding rows are no-ops
 # ---------------------------------------------------------------------------
 
-def test_padding_batches_are_bit_exact_noops():
+#: where a padded plan's live rows sit among its 8 steps
+LIVE_ROWS = {"trailing": [0, 1, 2], "leading": [5, 6, 7],
+             "interleaved": [1, 4, 6]}
+
+
+@pytest.mark.parametrize("where", sorted(LIVE_ROWS))
+def test_padding_batches_are_bit_exact_noops(where):
     """A padded subset epoch must leave (params, opt_state) bit-identical
-    to the unpadded epoch (same executable math, gated selects), and the
-    padding steps must report zero metric contribution."""
+    to the unpadded epoch wherever its padding rows sit (the scan skips
+    them), and the padding steps must report zero metric contribution."""
     m, units, tc, eng = _lm_engine()
     opt_init, _ = make_update_for(tc)
     idx = np.arange(6, dtype=np.int32)
@@ -144,10 +153,18 @@ def test_padding_batches_are_bit_exact_noops():
         p = m.init_params(jax.random.PRNGKey(1))
         o = opt_init(p)
         plan = eng.subset_plan(idx, w, epoch=0, pad_to_steps=pad_to_steps)
+        if pad_to_steps:
+            # move the live rows (the plan's first three) into place
+            rows = LIVE_ROWS[where]
+            order = rows + [r for r in range(pad_to_steps) if r not in rows]
+            plan = tuple(jnp.asarray(a)[np.argsort(order)] for a in plan)
         p, o, losses = eng.run_epoch(p, o, tc.lr, plan)
         return p, o, losses, plan
 
     pp, po, lp, plan_pad = run(eng.steps_per_epoch_max)  # maximal padding
+    assert eng.steps_per_epoch_max == 8
+    assert np.flatnonzero(eng.plan_live_steps(plan_pad)).tolist() == \
+        LIVE_ROWS[where]
     up, uo, lu, _ = run(0)                 # legacy unpadded shape
     for a, b in zip(jax.tree.leaves((pp, po)), jax.tree.leaves((up, uo))):
         assert np.array_equal(np.asarray(a), np.asarray(b)), \

@@ -242,11 +242,14 @@ def test_make_engine_dispatch_and_host_interface():
     live = scan.plan_live_steps(sp)
     assert np.array_equal(np.asarray(sp[0])[live], hp[0])
     # cost semantics: host charges the paper-style selected fraction
-    # (8 units), scan charges the bucketed steps it executes (2 of the
-    # 4 full-data steps at batch_units=2)
+    # (8 units), scan charges the live steps it executes (2 of the 4
+    # full-data steps at batch_units=2); padding rows run no step and
+    # are not charged
     assert host.epoch_cost(hp, n_selected=5) == pytest.approx(5 / 8)
     assert sp[0].shape == (2, 2)
     assert scan.epoch_cost(sp) == pytest.approx(0.5)
+    padded = scan.subset_plan(idx, w, epoch=0, pad_to_steps=4)
+    assert scan.epoch_cost(padded) == pytest.approx(0.5)
     # shard_state/restore_sharding are identity/None without a mesh
     p = {"w": np.zeros((2, 2), np.float32)}
     rp, ro = scan.shard_state(p, p)

@@ -256,11 +256,11 @@ def test_recurrent_resume_bit_exact(arch, tmp_path):
 
 
 def test_guard_composes_with_padding_gate_bitwise():
-    """The non-finite guard folds into the same ``step_on`` gate as the
-    weight-0 padding rows (DESIGN.md §10): on a plan mixing real and
-    padding rows, guard-on must be bit-identical to guard-off, padding
-    rows must not count as skipped, and a poisoned real row must gate
-    off exactly like a padding row."""
+    """The non-finite guard's gate leaves the same state as a weight-0
+    padding row that the scan skips (DESIGN.md §10): on a plan mixing
+    real and padding rows, guard-on must be bit-identical to guard-off,
+    padding rows must not count as skipped, and a poisoned real row must
+    gate off exactly like a padding row."""
     import dataclasses
     m, units, _, tc = _lm_setup(n=16, epochs=1)
     from repro.train.optim import make_update_for
@@ -301,6 +301,156 @@ def test_guard_composes_with_padding_gate_bitwise():
     for a, b in zip((p2, o2), (p4, o4)):
         assert all(np.array_equal(np.asarray(x), np.asarray(y))
                    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
+
+
+# ---------------------------------------------------------------------------
+# Padding rows run no step: an RNN-T smoke bundle whose loss bumps a host
+# counter each time a step runs it
+# ---------------------------------------------------------------------------
+
+#: the live rows of the 8-step padded plan (padding before, between and
+#: after them)
+PADDED_LIVE_ROWS = [2, 5]
+
+
+def _counting_rnnt(n_units=16):
+    import dataclasses
+    cfg = get_config("rnnt-crdnn-smoke")
+    base = build_model(cfg)
+    calls = [0]
+
+    def bump():
+        calls[0] += 1
+
+    def loss_fn(params, batch, **kw):
+        jax.debug.callback(bump)
+        return base.loss_fn(params, batch, **kw)
+
+    units = asr_units(make_asr_corpus(0, n_units, n_feats=cfg.rnnt.n_feats,
+                                      vocab_size=cfg.rnnt.vocab_size), 1)
+    return dataclasses.replace(base, loss_fn=loss_fn), units, calls
+
+
+def _padded_against_unpadded(engine: str, guard: bool) -> dict:
+    """Two live rows of 4 utterances, once padded to 8 steps with the live
+    rows at ``PADDED_LIVE_ROWS`` and once unpadded, each from the same
+    fresh state: the loss calls and ``epoch.gated_steps`` of each
+    dispatch, and whether the two agree bit for bit.  ``engine`` is
+    ``one_device``, ``pod`` (top-k compression with error feedback on a
+    (1, 1) ``data x pod`` mesh) or ``data_mesh`` (4 devices)."""
+    from repro import obs
+    from repro.launch.mesh import make_mesh
+    from repro.train.engine import Plan
+    from repro.train.optim import make_update_for
+    m, units, calls = _counting_rnnt()
+    mesh = {"one_device": lambda: None,
+            "pod": lambda: make_mesh((1, 1), ("data", "pod")),
+            "data_mesh": lambda: make_mesh((4,), ("data",))}[engine]()
+    tc = TrainConfig(lr=0.01, optimizer="adamw", nonfinite_guard=guard,
+                     compress_mode="topk" if engine == "pod" else "none",
+                     pgm=PGMConfig())
+    eng = EpochEngine(m, tc, units, batch_units=4, mesh=mesh)
+    opt_init, _ = make_update_for(tc)
+    ids = np.arange(8, dtype=np.int32)
+    w = np.linspace(0.5, 2.0, 8).astype(np.float32)
+    trailing = eng.subset_plan(ids, w, 0, pad_to_steps=8)
+    rest = [r for r in range(8) if r not in PADDED_LIVE_ROWS]
+    inv = np.argsort(PADDED_LIVE_ROWS + rest)
+    padded = Plan(trailing[0][inv], trailing[1][inv], trailing.counts)
+    unpadded = eng.subset_plan(ids, w, 0, pad_to_steps=0)
+    runs = []
+    for plan in (padded, unpadded):
+        p = m.init_params(jax.random.PRNGKey(0))
+        p, o = eng.shard_state(p, opt_init(p))
+        eng.compress_state = None
+        calls[0], gated = 0, obs.value("epoch.gated_steps")
+        p, o, losses = eng.run_epoch(p, o, tc.lr, plan)
+        state = jax.tree.map(np.asarray, (p, o, eng.compress_state))
+        jax.effects_barrier()
+        runs.append(dict(
+            calls=calls[0], gated=obs.value("epoch.gated_steps") - gated,
+            losses=np.asarray(losses), state=state,
+            skipped=None if not guard else
+            np.asarray(eng.last_skipped).tolist()))
+    live = eng.plan_live_steps(padded)
+    return {"calls": [r["calls"] for r in runs],
+            "gated": [r["gated"] for r in runs],
+            "live_rows": np.flatnonzero(live).tolist(),
+            "same_state": all(
+                np.array_equal(a, b) for a, b in zip(
+                    jax.tree.leaves(runs[0]["state"]),
+                    jax.tree.leaves(runs[1]["state"]))),
+            "same_live_losses": np.array_equal(runs[0]["losses"][live],
+                                               runs[1]["losses"]),
+            "padding_losses": runs[0]["losses"][~live].tolist(),
+            "skipped": [r["skipped"] for r in runs]}
+
+
+def _in_four_device_process(engine: str, guard: bool) -> dict:
+    import json
+    import os
+    import subprocess
+    import sys
+    here = os.path.dirname(os.path.abspath(__file__))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(here, "..", "src"),
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    code = (f"import json, sys; sys.path.insert(0, {here!r}); "
+            f"import test_train_engine as t; print(json.dumps("
+            f"t._padded_against_unpadded({engine!r}, {guard!r})))")
+    p = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, env=env, timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    return json.loads(p.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("engine", ["one_device", "pod", "data_mesh"])
+@pytest.mark.parametrize("guard", [False, True], ids=["no_guard", "guard"])
+def test_padding_rows_run_no_step(engine, guard):
+    """A padding row runs no step, wherever it sits in the plan: the loss
+    runs once per live row, the padded plan ends bit for bit where the
+    unpadded one does (the pod engine's error-feedback state included),
+    and ``epoch.gated_steps`` counts the padding rows skipped."""
+    got = (_in_four_device_process(engine, guard) if engine == "data_mesh"
+           else _padded_against_unpadded(engine, guard))
+    assert got["live_rows"] == PADDED_LIVE_ROWS
+    assert got["calls"] == [2, 2]
+    assert got["gated"] == [6, 0]
+    assert got["same_state"] and got["same_live_losses"]
+    assert got["padding_losses"] == [0.0] * 6
+    if guard:
+        # padding is skipped, not "skipped": the guard reports live steps
+        assert got["skipped"] == [[0.0] * 8, [0.0] * 2]
+
+
+def test_chunked_and_unpadded_epochs_count_their_steps():
+    """The chunked dispatch skips padding rows like ``run_epoch``; a full
+    plan and the host loop, whose plans are never padded, gate nothing."""
+    from repro import obs
+    from repro.train.engine import HostEngine
+    from repro.train.optim import make_update_for
+    m, units, calls = _counting_rnnt()
+    tc = TrainConfig(lr=0.01, optimizer="adamw", pgm=PGMConfig())
+    opt_init, _ = make_update_for(tc)
+    ids = np.arange(8, dtype=np.int32)
+    w = np.ones(8, np.float32)
+
+    def calls_and_gated(run):
+        p = m.init_params(jax.random.PRNGKey(0))
+        calls[0], gated = 0, obs.value("epoch.gated_steps")
+        run(p, opt_init(p))
+        jax.effects_barrier()
+        return calls[0], obs.value("epoch.gated_steps") - gated
+
+    eng = EpochEngine(m, tc, units, batch_units=4)
+    plans = [eng.subset_plan(ids, w, e, pad_to_steps=8) for e in (0, 1)]
+    assert calls_and_gated(lambda p, o: eng.run_epochs(
+        p, o, tc.lr, float("inf"), plans)) == (4, 12)
+    assert calls_and_gated(lambda p, o: eng.run_epoch(
+        p, o, tc.lr, eng.full_plan(0))) == (eng.steps_per_epoch_max, 0)
+    host = HostEngine(m, tc, units, batch_units=4)
+    assert calls_and_gated(lambda p, o: host.run_epoch(
+        p, o, tc.lr, host.subset_plan(ids, w, 0))) == (2, 0)
 
 
 def test_emergency_checkpoint_resume_bit_exact_mid_chunk(tmp_path):

@@ -31,9 +31,13 @@ _ARCH_MODULES: Dict[str, str] = {
     "recurrentgemma-9b": "recurrentgemma_9b",
     "paligemma-3b": "paligemma_3b",
     "rnnt-crdnn": "rnnt_crdnn",
+    "conformer-l-transducer": "conformer_l_transducer",
 }
 
-ASSIGNED_ARCHS: List[str] = [a for a in _ARCH_MODULES if a != "rnnt-crdnn"]
+#: the transducer architectures, which the LM dry-run cells leave out
+TRANSDUCER_ARCHS = ("rnnt-crdnn", "conformer-l-transducer")
+ASSIGNED_ARCHS: List[str] = [a for a in _ARCH_MODULES
+                             if a not in TRANSDUCER_ARCHS]
 
 
 def get_config(name: str) -> ModelConfig:

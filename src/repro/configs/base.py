@@ -154,11 +154,18 @@ class ModelConfig:
 
 @dataclass(frozen=True)
 class RNNTConfig:
-    """Paper's own architecture: SpeechBrain Librispeech transducer recipe.
+    """RNN-Transducer architectures: the paper's CRDNN and the Conformer.
 
-    CRDNN encoder (2 CNN blocks -> 4 bi-LSTM layers -> 2 DNN layers),
-    prediction net (embedding + 1-layer GRU), joint = single linear
-    projecting 1024-d fused representation to 1000 BPE vocab.
+    ``encoder="crdnn"`` (the defaults): SpeechBrain's Librispeech
+    transducer recipe — 2 CNN blocks -> 4 bi-LSTM layers -> 2 DNN layers,
+    prediction net embedding + 1-layer GRU, joint a single linear
+    projecting the 1024-d fused representation to 1000 BPE units.
+    ``encoder="conformer"`` (``models/conformer.py``): convolutional
+    subsampling by 4 to ``conformer_dim``, then ``conformer_blocks``
+    Conformer blocks; its batch norms keep running statistics as
+    non-trained state.  ``predictor``
+    is ``"gru"`` or ``"lstm"``.  The fields are flat (no nested groups)
+    so that a configuration file's keys map onto them one to one.
     """
 
     n_feats: int = 80
@@ -179,23 +186,60 @@ class RNNTConfig:
     # (<= 0: one chunk of the full vocab — right for smoke vocabs; set
     # to e.g. 512 when V is large enough that a (B,U+1,V) row dominates)
     loss_vocab_chunk: int = 0
+    encoder: str = "crdnn"           # crdnn | conformer
+    predictor: str = "gru"           # gru | lstm
+    # --- conformer encoder (encoder="conformer") ---
+    conformer_blocks: int = 17
+    conformer_dim: int = 512
+    conformer_heads: int = 8
+    conformer_ffn_dim: int = 2048
+    conformer_kernel: int = 32       # depthwise conv kernel
+    subsample_channels: int = 512
+
+    @property
+    def enc_dim(self) -> int:
+        """Width of the encoder's output frames."""
+        return self.conformer_dim if self.encoder == "conformer" \
+            else self.dnn_dim
+
+    @property
+    def pred_state(self) -> int:
+        """Width of the prediction network's recurrent state: the GRU's
+        hidden state, or the LSTM's hidden and cell states."""
+        return (2 if self.predictor == "lstm" else 1) * self.pred_hidden
 
     def n_params(self) -> int:
-        n = 0
-        c_in = 1
-        for c in self.cnn_channels:
-            n += c_in * c * 9 + c
-            c_in = c
-        feat = self.cnn_channels[-1] * (self.n_feats // 4)
-        d_in = feat
-        for _ in range(self.lstm_layers):
-            n += 2 * 4 * (d_in * self.lstm_hidden + self.lstm_hidden ** 2
-                          + self.lstm_hidden)
-            d_in = 2 * self.lstm_hidden
-        n += d_in * self.dnn_dim + self.dnn_dim * self.dnn_dim
+        """Trained parameters (batch norm's running statistics are not)."""
+        if self.encoder == "conformer":
+            d, f, c = self.conformer_dim, self.conformer_ffn_dim, \
+                self.subsample_channels
+            n = 9 * c + c + 9 * c * c + c + c * (self.n_feats // 4) * d + d
+            ffn = 2 * d + d * f + f + f * d + d
+            mhsa = 2 * d + 4 * (d * d + d) + d * d + 2 * d
+            conv = (2 * d + d * 2 * d + 2 * d + self.conformer_kernel * d
+                    + d + 2 * d + d * d + d)
+            n += self.conformer_blocks * (2 * ffn + mhsa + conv + 2 * d)
+        else:
+            n = 0
+            c_in = 1
+            for c in self.cnn_channels:
+                n += c_in * c * 9 + c
+                c_in = c
+            feat = self.cnn_channels[-1] * (self.n_feats // 4)
+            d_in = feat
+            for _ in range(self.lstm_layers):
+                n += 2 * 4 * (d_in * self.lstm_hidden
+                              + self.lstm_hidden ** 2 + self.lstm_hidden)
+                d_in = 2 * self.lstm_hidden
+            n += d_in * self.dnn_dim + self.dnn_dim * self.dnn_dim
         n += self.vocab_size * self.pred_embed
-        n += 3 * (self.pred_embed * self.pred_hidden + self.pred_hidden ** 2)
-        n += (self.dnn_dim + self.pred_hidden) * self.joint_dim
+        if self.predictor == "lstm":
+            n += 4 * (self.pred_embed * self.pred_hidden
+                      + self.pred_hidden ** 2 + self.pred_hidden)
+        else:
+            n += 3 * (self.pred_embed * self.pred_hidden
+                      + self.pred_hidden ** 2)
+        n += (self.enc_dim + self.pred_hidden) * self.joint_dim
         n += self.joint_dim * self.vocab_size
         return n
 
@@ -312,7 +356,13 @@ def reduce_for_smoke(cfg: ModelConfig) -> ModelConfig:
         kw["moe"] = MoEConfig(
             n_experts=4, top_k=min(cfg.moe.top_k, 2), d_ff_expert=32
         )
-    if cfg.rnnt is not None:
+    if cfg.rnnt is not None and cfg.rnnt.encoder == "conformer":
+        kw["rnnt"] = dataclasses.replace(
+            cfg.rnnt, n_feats=8, conformer_blocks=2, conformer_dim=32,
+            conformer_heads=4, conformer_ffn_dim=64, conformer_kernel=4,
+            subsample_channels=8, pred_embed=16, pred_hidden=16,
+            joint_dim=32, vocab_size=37)
+    elif cfg.rnnt is not None:
         kw["rnnt"] = RNNTConfig(
             n_feats=8, cnn_channels=(4, 8), lstm_layers=1, lstm_hidden=16,
             dnn_dim=32, pred_embed=16, pred_hidden=16, joint_dim=32,

@@ -15,6 +15,10 @@ The per-token error scaling matches the training loss exactly:
 per-example mean over tokens, then mean over examples, i.e.
 ``E[b,s] = (softmax - onehot) * mask[b,s] / (n_tok_b * B)``.
 
+Models with batch norm (the Conformer transducer) are read in evaluation
+mode: the running statistics in the params normalize, so a unit's
+gradient does not depend on the other units of its batch or chunk.
+
 Family coverage beyond dense LMs / RNN-T (DESIGN.md §8):
 
 * **Sparse-expert (MoE)** — the last-layer head gradient is blind to the
@@ -167,10 +171,10 @@ def rnnt_joint_grad(bundle, params, batch, shard=None) -> jax.Array:
     r = cfg.rnnt
     shard = shard or IDENTITY_SHARDER
     ze, zp = rnnt_mod.joint_factors(params, cfg, batch["feats"],
-                                    batch["tokens"])
+                                    batch["tokens"], batch["feat_lens"])
     ze = shard(ze, "act_bsd")
     zp = shard(zp, "act_bsd")
-    t_lens = jnp.maximum(batch["feat_lens"] // r.time_reduction, 1)
+    t_lens = rnnt_mod.encoder_lengths(cfg, batch["feat_lens"])
     scale = _rnnt_per_example_nll_scale(batch)
 
     def loss_of_head(w_out):
@@ -189,14 +193,13 @@ def rnnt_unit_factors(bundle, params, batch, shard=None):
     from repro.models import rnnt as rnnt_mod
     from repro.models.common import IDENTITY_SHARDER
     cfg = bundle.cfg
-    r = cfg.rnnt
     z, _, _, _ = bundle.final_hidden(
         params, batch, shard=shard or IDENTITY_SHARDER)        # (B,T,U1,J)
     w_out = bundle.head_weight(params)
 
     def loss_of_logits(logits):
         from repro.core.rnnt_loss import rnnt_loss_from_logits
-        t_lens = jnp.maximum(batch["feat_lens"] // r.time_reduction, 1)
+        t_lens = rnnt_mod.encoder_lengths(cfg, batch["feat_lens"])
         per_ex = rnnt_loss_from_logits(logits, batch["tokens"], t_lens,
                                        batch["token_lens"])
         per_ex = per_ex / jnp.maximum(batch["token_lens"].astype(jnp.float32),

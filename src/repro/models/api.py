@@ -18,7 +18,7 @@ from repro.models import encdec as encdec_mod
 from repro.models import rnnt as rnnt_mod
 from repro.models import transformer as tfm
 from repro.models.attention import prefix_lm_mask
-from repro.models.common import IDENTITY_SHARDER, Sharder
+from repro.models.common import IDENTITY_SHARDER, STATE_KEY, Sharder
 
 Batch = Dict[str, jax.Array]
 
@@ -324,9 +324,10 @@ def _build_rnnt(cfg: ModelConfig) -> ModelBundle:
                          f"got {r.loss_impl!r}")
 
     def _t_lens(batch):
-        return jnp.maximum(batch["feat_lens"] // r.time_reduction, 1)
+        return rnnt_mod.encoder_lengths(cfg, batch["feat_lens"])
 
-    def per_example_nll(params, batch, shard=IDENTITY_SHARDER):
+    def per_example_nll(params, batch, shard=IDENTITY_SHARDER, train=False,
+                        remat=True):
         """Per-example transducer NLL, path keyed by ``r.loss_impl``
         (DESIGN.md §2): ``fused`` streams the joint inside a custom_vjp
         (no (B,T,U+1,V) tensor, analytic gradients); ``dense`` is the
@@ -335,33 +336,64 @@ def _build_rnnt(cfg: ModelConfig) -> ModelBundle:
         replicated elsewhere) — on a mesh this anchors GSPMD's
         propagation at the custom_vjp boundary, which XLA:CPU SPMD
         otherwise mispartitions through the CRDNN encoder (wrong
-        *values*, not just reordering; see tests/test_sharded_engine.py)."""
+        *values*, not just reordering; see tests/test_sharded_engine.py).
+
+        -> ``(nll (B,), state)``: with ``train`` the encoder's batch norms
+        (if any) take the batch's statistics and ``state`` is the running
+        statistics after it; otherwise the running statistics normalize
+        and ``state`` is None."""
         if r.loss_impl == "fused":
-            ze, zp = rnnt_mod.joint_factors(params, cfg, batch["feats"],
-                                            batch["tokens"])
+            if train:
+                ze, zp, state = rnnt_mod.joint_factors_train(
+                    params, cfg, batch["feats"], batch["tokens"],
+                    batch["feat_lens"], remat=remat)
+            else:
+                ze, zp = rnnt_mod.joint_factors(
+                    params, cfg, batch["feats"], batch["tokens"],
+                    batch["feat_lens"])
+                state = None
             ze = shard(ze, "act_bsd")
             zp = shard(zp, "act_bsd")
             return rnnt_loss_fused(
                 ze, zp, params["joint"]["w_out"], batch["tokens"],
                 _t_lens(batch), batch["token_lens"],
                 vocab_chunk=r.loss_vocab_chunk,
-                lattice_part=shard.batch_partition(ze.shape[0]))
-        logits = rnnt_mod.forward(params, cfg, batch["feats"], batch["tokens"])
+                lattice_part=shard.batch_partition(ze.shape[0])), state
+        if train and r.encoder == "conformer":
+            raise NotImplementedError(
+                "the dense (oracle) loss trains the CRDNN only; the "
+                "Conformer trains on the fused loss")
+        logits = rnnt_mod.forward(params, cfg, batch["feats"], batch["tokens"],
+                                  batch["feat_lens"])
         return rnnt_loss_from_logits(logits, batch["tokens"], _t_lens(batch),
-                                     batch["token_lens"])
+                                     batch["token_lens"]), None
+
+    def _per_token(nll, batch):
+        return nll / jnp.maximum(batch["token_lens"].astype(jnp.float32), 1.0)
 
     def per_example_loss(params, batch, shard=IDENTITY_SHARDER, remat=True):
-        return per_example_nll(params, batch, shard) \
-            / jnp.maximum(batch["token_lens"].astype(jnp.float32), 1.0)
+        """Evaluation: the running statistics normalize (validation,
+        selection)."""
+        nll, _ = per_example_nll(params, batch, shard)
+        return _per_token(nll, batch)
 
     def loss_fn(params, batch, shard=IDENTITY_SHARDER, remat=True):
-        per_ex = per_example_loss(params, batch, shard, remat)
-        return _weighted(per_ex, batch, jnp.zeros((), jnp.float32))
+        """The training objective.  An encoder with batch norm normalizes
+        with the batch's statistics and reports the running statistics
+        after this batch as ``metrics[STATE_KEY]``, for the training step
+        to put in place of the params' (``make_step_core``)."""
+        nll, state = per_example_nll(params, batch, shard, train=True,
+                                     remat=remat)
+        total, metrics = _weighted(_per_token(nll, batch), batch,
+                                   jnp.zeros((), jnp.float32))
+        if state is not None:
+            metrics[STATE_KEY] = state
+        return total, metrics
 
     def hidden(params, batch, shard=IDENTITY_SHARDER, remat=True):
         """PGM hook: joint pre-vocab activations + what's needed for the
         loss-to-logits error signal."""
-        enc = rnnt_mod.encode(params, cfg, batch["feats"])
+        enc = rnnt_mod.encode(params, cfg, batch["feats"], batch["feat_lens"])
         pred = rnnt_mod.predict(params, cfg, batch["tokens"])
         z = rnnt_mod.joint_hidden(params, enc, pred)
         return z, batch["tokens"], None, jnp.zeros((), jnp.float32)
@@ -396,7 +428,7 @@ def _build_rnnt(cfg: ModelConfig) -> ModelBundle:
 
     # -- streaming greedy transducer serve hooks (DESIGN.md §4) --------
     # The LM serve contract maps onto the transducer search: "prefill"
-    # runs the CRDNN encoder once and seeds the blank-start prediction
+    # runs the encoder once and seeds the blank-start prediction
     # state; "decode" is one *joint step* — it consumes the previously
     # sampled symbol (blank advances the frame cursor, a label advances
     # the prediction GRU) and returns the next joint logits.  The cache
@@ -409,7 +441,7 @@ def _build_rnnt(cfg: ModelConfig) -> ModelBundle:
     def rnnt_prefill(params, batch, shard=IDENTITY_SHARDER, cache_len=None,
                      max_symbols: int = 8):
         feats = batch["feats"]
-        enc = rnnt_mod.encode(params, cfg, feats)
+        enc = rnnt_mod.encode(params, cfg, feats, batch["feat_lens"])
         B, T_enc, _ = enc.shape
         t_len = jnp.minimum(_t_lens(batch), T_enc).astype(jnp.int32)
         g, h = rnnt_mod.pred_start(params, cfg, B, dtype=enc.dtype)
@@ -453,11 +485,11 @@ def _build_rnnt(cfg: ModelConfig) -> ModelBundle:
         capacity (audio frames // time_reduction)."""
         dtype = jnp.float32 if dtype is None else dtype
         return {
-            "enc": jnp.zeros((batch_size, cache_len, r.dnn_dim), dtype),
+            "enc": jnp.zeros((batch_size, cache_len, r.enc_dim), dtype),
             "t": jnp.zeros((batch_size,), jnp.int32),
             "t_len": jnp.zeros((batch_size,), jnp.int32),
             "g": jnp.zeros((batch_size, r.pred_hidden), dtype),
-            "h": jnp.zeros((batch_size, r.pred_hidden), dtype),
+            "h": jnp.zeros((batch_size, r.pred_state), dtype),
             "syms": jnp.zeros((batch_size,), jnp.int32),
             "max_syms": jnp.full((batch_size,), max_symbols, jnp.int32),
         }

@@ -40,6 +40,34 @@ IDENTITY_SHARDER = Sharder()
 
 
 # ---------------------------------------------------------------------------
+# Non-trained state inside the params tree
+# ---------------------------------------------------------------------------
+
+#: top-level params key of a model's non-trained state (batch norm's
+#: running statistics).  The optimizer never sees it
+#: (``train/optim.py:make_update_for``); the training step replaces it
+#: with the state its loss reports under the same key in its metrics
+#: (``train/engine.py:make_step_core``); every other use of the params
+#: (validation, selection, serving, checkpoints) reads it as it is.
+STATE_KEY = "running_stats"
+
+
+def split_state(params):
+    """-> (trained params, state); the state is None where the params
+    carry none, and the trained params are then ``params`` itself."""
+    if isinstance(params, dict) and STATE_KEY in params:
+        return ({k: v for k, v in params.items() if k != STATE_KEY},
+                params[STATE_KEY])
+    return params, None
+
+
+def merge_state(params, state):
+    """The params tree with ``state`` under ``STATE_KEY`` (as it is when
+    ``state`` is None)."""
+    return params if state is None else {**params, STATE_KEY: state}
+
+
+# ---------------------------------------------------------------------------
 # Initializers
 # ---------------------------------------------------------------------------
 

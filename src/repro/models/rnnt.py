@@ -1,10 +1,20 @@
-"""The paper's own architecture: CRDNN RNN-Transducer (SpeechBrain
-Librispeech transducer recipe; Graves 2012, Ravanelli et al. 2021).
+"""RNN-Transducer models: the paper's CRDNN (SpeechBrain Librispeech
+transducer recipe; Graves 2012, Ravanelli et al. 2021) and the Conformer
+transducer (Gulati et al. 2020), selected by ``RNNTConfig.encoder``.
 
-Transcription network: 2 CNN blocks (3x3, stride 2x2) -> 4 bi-LSTM layers
--> 2 DNN layers.  Prediction network: embedding + 1-layer GRU.  Joint
-network: Linear(enc) + Linear(pred) -> tanh -> Linear to vocab (the layer
-whose gradient PGM matches).
+Transcription network: ``encoder="crdnn"`` is 2 CNN blocks (3x3, stride
+2x2) -> 4 bi-LSTM layers -> 2 DNN layers, blind to padding;
+``encoder="conformer"`` is ``models/conformer.py``: a length-aware
+convolutional subsampling and a stack of Conformer blocks whose batch
+norms keep running statistics as non-trained state (the params tree's
+``STATE_KEY`` subtree, ``models/common.py``).  Prediction network
+(``predictor``): embedding + 1-layer GRU or LSTM.  Joint network:
+Linear(enc) + Linear(pred) -> tanh -> Linear to vocab (the layer whose
+gradient PGM matches).
+
+The functions below run in evaluation mode (running statistics);
+``joint_factors_train`` is the training forward, which normalizes with
+the batch's statistics and reports the running statistics after it.
 """
 from __future__ import annotations
 
@@ -13,7 +23,9 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import dense_init, embed_init, split
+from repro.models import conformer
+from repro.models.common import (STATE_KEY, dense_init, embed_init,
+                                 split)
 
 
 # ---------------------------------------------------------------------------
@@ -27,8 +39,9 @@ def init_lstm(key, d_in, d_h):
             "b": jnp.zeros((4 * d_h,))}
 
 
-def lstm_scan(p, x, reverse=False):
-    """x: (B,T,d_in) -> (B,T,d_h)."""
+def lstm_scan(p, x, reverse=False, state=None, return_state=False):
+    """x: (B,T,d_in) -> (B,T,d_h); from ``state`` = (h, c) (zeros when
+    None), and with the last (h, c) when ``return_state``."""
     B, T, _ = x.shape
     d_h = p["wh"].shape[0]
     xw = x @ p["wx"].astype(x.dtype) + p["b"].astype(x.dtype)
@@ -41,10 +54,20 @@ def lstm_scan(p, x, reverse=False):
         h = jax.nn.sigmoid(o) * jnp.tanh(c)
         return (h, c), h
 
-    h0 = jnp.zeros((B, d_h), x.dtype)
-    _, hs = jax.lax.scan(step, (h0, h0), jnp.moveaxis(xw, 1, 0),
-                         reverse=reverse)
-    return jnp.moveaxis(hs, 0, 1)
+    if state is None:
+        h0 = jnp.zeros((B, d_h), x.dtype)
+        state = (h0, h0)
+    last, hs = jax.lax.scan(step, state, jnp.moveaxis(xw, 1, 0),
+                            reverse=reverse)
+    hs = jnp.moveaxis(hs, 0, 1)
+    return (hs, last) if return_state else hs
+
+
+def lstm_step(p, x_t, h, c):
+    """Single LSTM step for greedy transducer decoding: x_t (B,d_in) ->
+    (y, (h, c))."""
+    y, last = lstm_scan(p, x_t[:, None], state=(h, c), return_state=True)
+    return y[:, 0], last
 
 
 def init_gru(key, d_in, d_h):
@@ -91,9 +114,7 @@ BLANK_ID = 0
 # RNN-T model
 # ---------------------------------------------------------------------------
 
-def init_params(cfg, key) -> Dict:
-    r = cfg.rnnt
-    ks = split(key, 16)
+def _init_crdnn(r, ks) -> Dict:
     p: Dict = {}
     c_in = 1
     for i, c in enumerate(r.cnn_channels):
@@ -113,20 +134,40 @@ def init_params(cfg, key) -> Dict:
                  "b": jnp.zeros((r.dnn_dim,))}
     p["dnn1"] = {"w": dense_init(ks[13], r.dnn_dim, r.dnn_dim),
                  "b": jnp.zeros((r.dnn_dim,))}
+    return p
+
+
+def init_params(cfg, key) -> Dict:
+    r = cfg.rnnt
+    ks = split(key, 16)
+    if r.encoder == "conformer":
+        p = conformer.init_params(r, ks[0])
+        p[STATE_KEY] = conformer.init_stats(r)
+    else:
+        p = _init_crdnn(r, ks)
     p["pred_embed"] = {"w": embed_init(ks[14], r.vocab_size, r.pred_embed)}
-    p["pred_gru"] = init_gru(ks[15], r.pred_embed, r.pred_hidden)
+    if r.predictor == "lstm":
+        p["pred_lstm"] = init_lstm(ks[15], r.pred_embed, r.pred_hidden)
+    else:
+        p["pred_gru"] = init_gru(ks[15], r.pred_embed, r.pred_hidden)
     kj = split(jax.random.fold_in(key, 7), 3)
     p["joint"] = {
-        "w_enc": dense_init(kj[0], r.dnn_dim, r.joint_dim),
+        "w_enc": dense_init(kj[0], r.enc_dim, r.joint_dim),
         "w_pred": dense_init(kj[1], r.pred_hidden, r.joint_dim),
         "w_out": dense_init(kj[2], r.joint_dim, r.vocab_size),
     }
     return p
 
 
-def encode(params, cfg, feats):
-    """feats: (B,T,F) -> (B, T//4, dnn_dim)."""
-    r = cfg.rnnt
+def encoder_lengths(cfg, feat_lens):
+    """Live encoder frames (the transducer lattice's length) of
+    utterances of ``feat_lens`` input frames."""
+    if cfg.rnnt.encoder == "conformer":
+        return conformer.encoder_lengths(feat_lens)
+    return jnp.maximum(feat_lens // cfg.rnnt.time_reduction, 1)
+
+
+def _encode_crdnn(params, r, feats):
     with jax.named_scope("cnn"):
         x = feats[..., None]                              # (B,T,F,1)
         for i in range(len(r.cnn_channels)):
@@ -150,23 +191,34 @@ def encode(params, cfg, feats):
     return x
 
 
+def encode(params, cfg, feats, feat_lens=None):
+    """feats: (B,T,F) -> (B, T', enc_dim), T' = T//4 (CRDNN) or
+    ceil(T/4) (Conformer).  ``feat_lens`` (B,) are the live frames; the
+    CRDNN ignores them, the Conformer masks the rest (all frames are live
+    when None)."""
+    r = cfg.rnnt
+    if r.encoder != "conformer":
+        return _encode_crdnn(params, r, feats)
+    if feat_lens is None:
+        feat_lens = jnp.full(feats.shape[:1], feats.shape[1], jnp.int32)
+    x, _ = conformer.encode(params, r, params[STATE_KEY], feats, feat_lens)
+    return x
+
+
 def predict(params, cfg, tokens):
     """tokens: (B,U) -> (B, U+1, pred_hidden): position u conditions on
     tokens[<u]; position 0 is the blank-start state."""
-    with jax.named_scope("pred_gru"):
+    lstm = cfg.rnnt.predictor == "lstm"
+    with jax.named_scope("pred_lstm" if lstm else "pred_gru"):
         emb = jnp.take(params["pred_embed"]["w"], tokens, axis=0)
         emb = jnp.pad(emb, ((0, 0), (1, 0), (0, 0)))      # start token = 0
+        if lstm:
+            return lstm_scan(params["pred_lstm"], emb)
         g, _ = gru_scan(params["pred_gru"], emb)
     return g
 
 
-def joint_factors(params, cfg, feats, tokens):
-    """Factors of the joint for the fused transducer loss (DESIGN.md §2):
-    -> (ze (B,T',J), zp (B,U+1,J)).  ``tanh(ze[:,:,None] + zp[:,None])``
-    is ``joint_hidden``; the fused loss (``core/rnnt_loss.py``) forms it
-    row-by-row inside its scan instead of materializing (B,T',U+1,J)."""
-    enc = encode(params, cfg, feats)
-    pred = predict(params, cfg, tokens)
+def _joint_proj(params, enc, pred):
     dt = enc.dtype
     with jax.named_scope("joint_proj"):
         ze = enc @ params["joint"]["w_enc"].astype(dt)    # (B,T,J)
@@ -174,18 +226,49 @@ def joint_factors(params, cfg, feats, tokens):
     return ze, zp
 
 
+def joint_factors(params, cfg, feats, tokens, feat_lens=None):
+    """Factors of the joint for the fused transducer loss (DESIGN.md §2):
+    -> (ze (B,T',J), zp (B,U+1,J)).  ``tanh(ze[:,:,None] + zp[:,None])``
+    is ``joint_hidden``; the fused loss (``core/rnnt_loss.py``) forms it
+    row-by-row inside its scan instead of materializing (B,T',U+1,J)."""
+    enc = encode(params, cfg, feats, feat_lens)
+    return _joint_proj(params, enc, predict(params, cfg, tokens))
+
+
+def joint_factors_train(params, cfg, feats, tokens, feat_lens,
+                        remat: bool = True):
+    """The training forward's joint factors: -> ``(ze, zp, state)``.  For
+    the Conformer, batch norm takes the batch's statistics over live
+    frames, each block is rematerialized when ``remat``, and ``state`` is
+    the running statistics after this batch; for the CRDNN this is
+    ``joint_factors`` and ``state`` is None."""
+    r = cfg.rnnt
+    if r.encoder != "conformer":
+        return joint_factors(params, cfg, feats, tokens) + (None,)
+    enc, state = conformer.encode(params, r, params[STATE_KEY], feats,
+                                  feat_lens, train=True, remat=remat)
+    return _joint_proj(params, enc, predict(params, cfg, tokens)) + (state,)
+
+
 def pred_step(params, cfg, tokens, h):
     """One prediction-network step for streaming greedy decode.
 
     ``tokens``: (B,) int32 — the symbol just emitted; any id < 0 means
     the blank-start state (a zero embedding, exactly what ``predict``
-    feeds at position 0 via its left pad).  ``h``: (B, pred_hidden) GRU
-    state.  Returns ``(g (B, pred_hidden), h_new)`` — feeding the label
-    sequence through this step token by token reproduces ``predict``'s
-    rows exactly (tests/test_serve_engine.py).
+    feeds at position 0 via its left pad).  ``h``: (B, pred_state) state
+    (``RNNTConfig.pred_state``: the GRU's hidden state, or the LSTM's
+    hidden and cell states side by side).  Returns ``(g (B,
+    pred_hidden), h_new)`` — feeding the label sequence through this step
+    token by token reproduces ``predict``'s rows exactly
+    (tests/test_serve_engine.py).
     """
     emb = jnp.take(params["pred_embed"]["w"], jnp.maximum(tokens, 0), axis=0)
     emb = jnp.where((tokens >= 0)[:, None], emb, 0.0)
+    if cfg.rnnt.predictor == "lstm":
+        d_h = cfg.rnnt.pred_hidden
+        y, (hh, c) = lstm_step(params["pred_lstm"], emb, h[:, :d_h],
+                               h[:, d_h:])
+        return y, jnp.concatenate([hh, c], axis=-1)
     return gru_step(params["pred_gru"], emb, h)
 
 
@@ -193,7 +276,7 @@ def pred_start(params, cfg, batch_size: int, dtype=jnp.float32):
     """Blank-start prediction state: ``(g0, h0)`` — what ``predict``
     produces at u=0 before any label is consumed."""
     r = cfg.rnnt
-    h0 = jnp.zeros((batch_size, r.pred_hidden), dtype)
+    h0 = jnp.zeros((batch_size, r.pred_state), dtype)
     return pred_step(params, cfg, jnp.full((batch_size,), -1, jnp.int32), h0)
 
 
@@ -221,9 +304,9 @@ def joint_logits(params, z):
     return z @ params["joint"]["w_out"].astype(z.dtype)
 
 
-def forward(params, cfg, feats, tokens):
+def forward(params, cfg, feats, tokens, feat_lens=None):
     """-> logits (B, T', U+1, V)."""
-    enc = encode(params, cfg, feats)
+    enc = encode(params, cfg, feats, feat_lens)
     pred = predict(params, cfg, tokens)
     z = joint_hidden(params, enc, pred)
     return joint_logits(params, z)

@@ -559,10 +559,10 @@ def rnnt_greedy_reference(bundle, params, feats, feat_lens,
     must match token-for-token (tests/test_serve_engine.py)."""
     from repro.models import rnnt as rnnt_mod
     cfg = bundle.cfg
-    enc = rnnt_mod.encode(params, cfg, jnp.asarray(feats))
-    red = cfg.rnnt.time_reduction
-    t_lens = np.minimum(
-        np.maximum(np.asarray(feat_lens) // red, 1), enc.shape[1])
+    enc = rnnt_mod.encode(params, cfg, jnp.asarray(feats),
+                          jnp.asarray(feat_lens))
+    t_lens = np.minimum(np.asarray(rnnt_mod.encoder_lengths(
+        cfg, jnp.asarray(feat_lens))), enc.shape[1])
     results: List[List[int]] = []
     for b in range(enc.shape[0]):
         g, h = rnnt_mod.pred_start(params, cfg, 1, dtype=enc.dtype)

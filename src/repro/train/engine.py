@@ -57,6 +57,7 @@ from repro.configs.base import TrainConfig
 from repro.data.pipeline import (PlanCounts, epoch_plan, padded_length,
                                  plan_counts, subset_epoch_plan,
                                  unit_durations)
+from repro.models.common import STATE_KEY, merge_state, split_state
 from repro.train.compress import compressed_psum, init_error_state
 from repro.train.optim import (clip_by_global_norm, gate_step,
                                make_update_for)
@@ -120,6 +121,15 @@ def make_step_core(bundle, cfg: TrainConfig, shard=None, pod=None):
     (params, opt_state, metrics, err)``; on a gated-off step the error
     state is returned bit-identically (``optim.gate_step``).
 
+    Model state (``models/common.py:STATE_KEY``, batch norm's running
+    statistics) rides in the params: the gradient and the optimizer see
+    the trained params only (the optimizer state is initialised from
+    them, ``split_state(params)[0]``), and the step puts in the state's
+    place the one the loss reports in its metrics, under the same gate
+    as the update (so a gated-off step leaves it too).  A model without
+    state takes exactly the program it took before.  The pod step keeps
+    no model state.
+
     Aux losses (e.g. the MoE router load-balance penalty) are computed
     per pod and pod-averaged — the standard data-parallel approximation
     (each replica balances its local sub-batch).  For aux-free families
@@ -144,7 +154,10 @@ def make_step_core(bundle, cfg: TrainConfig, shard=None, pod=None):
 
     if pod is None:
         def step(params, opt_state, batch, lr, step_on=None):
+            trained, state = split_state(params)
+
             def loss(p):
+                p = merge_state(p, state)
                 if shard is None:
                     total, metrics = bundle.loss_fn(p, batch)
                 else:
@@ -152,7 +165,9 @@ def make_step_core(bundle, cfg: TrainConfig, shard=None, pod=None):
                 return total, metrics
 
             (l, metrics), grads = jax.value_and_grad(loss,
-                                                     has_aux=True)(params)
+                                                     has_aux=True)(trained)
+            metrics = dict(metrics)
+            new_state = metrics.pop(STATE_KEY, None)
             with jax.named_scope("grad_clip"):
                 grads, gnorm = clip_by_global_norm(grads, cfg.grad_clip)
             if guard:
@@ -166,8 +181,13 @@ def make_step_core(bundle, cfg: TrainConfig, shard=None, pod=None):
             else:
                 ok = step_on
             with jax.named_scope("optimizer"):
-                params, opt_state = opt_update(params, grads, opt_state, lr,
-                                               step_on=ok)
+                trained, opt_state = opt_update(trained, grads, opt_state,
+                                                lr, step_on=ok)
+                if new_state is None:
+                    new_state = state
+                elif ok is not None:
+                    new_state = gate_step(ok, new_state, state)
+                params = merge_state(trained, new_state)
             metrics = dict(metrics, grad_norm=gnorm)
             if ok is not None:
                 metrics = {k: jnp.where(ok, v, jnp.zeros_like(v))
@@ -190,6 +210,10 @@ def make_step_core(bundle, cfg: TrainConfig, shard=None, pod=None):
                              P(pod.axis, ax, *([None] * (v.ndim - 2)))))
 
     def pod_step(params, opt_state, batch, lr, err, step_on=None):
+        if split_state(params)[1] is not None:
+            raise NotImplementedError(
+                "the data x pod step keeps no model state (batch norm's "
+                "running statistics); train such a model on a data mesh")
         bp = {k: split_pods(v) for k, v in batch.items()}
 
         def per_pod(b_k, e_k):

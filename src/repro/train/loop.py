@@ -51,6 +51,7 @@ from repro.core.metrics import overlap_index
 from repro.core.pgm import ResidentSelector, Selection, pgm_select
 from repro.data.pipeline import unit_durations
 from repro.data.plan_prefetch import PlanPrefetcher
+from repro.models.common import split_state
 from repro.train import checkpoint as ckpt_mod
 from repro.train import faults as faults_mod
 from repro.train.engine import EpochEngine, make_engine, make_step_core
@@ -158,7 +159,8 @@ def train_with_selection(
     key = jax.random.PRNGKey(tc.seed) if key is None else key
     params = bundle.init_params(key)
     opt_init, _ = make_update_for(tc)
-    opt_state = opt_init(params)
+    # the optimizer mirrors the trained params, not a model's state
+    opt_state = opt_init(split_state(params)[0])
     # bring the donated carry onto the mesh (identity without one)
     params, opt_state = eng.shard_state(params, opt_state)
     units_dev = eng.units
@@ -442,7 +444,7 @@ def train_with_selection(
                         key = jax.random.fold_in(key,
                                                  7919 + hist.rollbacks)
                         params = bundle.init_params(key)
-                        opt_state = opt_init(params)
+                        opt_state = opt_init(split_state(params)[0])
                         params, opt_state = eng.shard_state(params,
                                                             opt_state)
                         if uses_err:

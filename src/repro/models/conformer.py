@@ -35,10 +35,13 @@ utterances fits on one 16 GB chip)::
 **MHSA** (Transformer-XL relative positions, Dai et al. 2019): pre-LN;
 per head ``score(i, j) = [(q_i + u) . k_j + (q_i + v) . (W_R R_{i-j})] /
 sqrt(dh)``, where ``R`` is the sinusoidal encoding (``[sin | cos]``
-halves) of ``i - j`` in ``[-(T'-1), T'-1]``, ``W_R`` has no bias and
-``u``, ``v`` are learned per head.  Padded keys are masked before the
-softmax.  The position term is formed against all ``2T' - 1`` relative
-positions and moved into place with the pad-and-reshape shift.
+halves) of ``i - j`` at the ``d/2`` frequencies ``w_f = 10000^(-2f/d)``,
+``W_R`` has no bias and ``u``, ``v`` are learned per head.  Padded keys
+are masked before the softmax.  The position term is formed at ``(T',
+T')`` directly, by the angle-difference identity: with ``a = W_R[:, h]
+(q_i + v)_h`` in sin and cos halves and ``p_i = i w``, it is ``Q_i . K_j``
+for ``Q_i = [a^s sin p_i + a^c cos p_i | a^c sin p_i - a^s cos p_i]`` and
+``K_j = [cos p_j | sin p_j]``, one ``K`` for every batch row and head.
 
 **Conv module:** pre-LN, pointwise ``d -> 2d``, GLU, padded frames zeroed,
 depthwise conv of kernel ``K`` with 'SAME' padding (``(K-1)//2`` before,
@@ -177,22 +180,21 @@ def _ffn(p, x):
     return h @ p["w2"].astype(x.dtype) + p["b2"].astype(x.dtype)
 
 
-def rel_positions(t: int, d: int, dtype=jnp.float32):
-    """Sinusoidal encodings of the relative positions ``t-1, t-2, ...,
-    -(t-1)``, ``[sin | cos]`` halves: ``(2t - 1, d)``."""
-    pos = jnp.arange(t - 1, -t, -1, dtype=jnp.float32)
-    inv = 1.0 / (10000.0 ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = pos[:, None] * inv[None, :]
-    return jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], -1).astype(dtype)
-
-
-def _rel_shift(x):
-    """``(..., t, 2t-1)`` scores against relative positions ``t-1 ...
-    -(t-1)`` -> ``(..., t, t)`` with ``out[i, j]`` at position ``i - j``."""
-    *lead, t, _ = x.shape
-    pad = [(0, 0)] * len(lead) + [(0, 0), (1, 0)]
-    x = jnp.pad(x, pad).reshape(*lead, 2 * t, t)[..., 1:, :]
-    return x.reshape(*lead, t, 2 * t - 1)[..., :t]
+def _position_scores(qv, w_pos):
+    """The Transformer-XL position term ``(q_i + v) . (W_R R_{i-j})`` of
+    each head: ``qv = q + v`` (B, T, H, dh), ``w_pos`` (d, d) -> (B, H, T,
+    T), by the angle-difference identity of the module's docstring."""
+    _, T, H, dh = qv.shape
+    d = H * dh
+    dt = qv.dtype
+    a = jnp.einsum("bihe,mhe->bhim", qv, w_pos.reshape(d, H, dh).astype(dt))
+    w = 1.0 / (10000.0 ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    ang = jnp.arange(T, dtype=jnp.float32)[:, None] * w
+    sin, cos = jnp.sin(ang).astype(dt), jnp.cos(ang).astype(dt)  # (T, d/2)
+    a_s, a_c = a[..., :d // 2], a[..., d // 2:]
+    q_rot = jnp.concatenate([a_s * sin + a_c * cos, a_c * sin - a_s * cos],
+                            -1)
+    return jnp.einsum("bhim,jm->bhij", q_rot, jnp.concatenate([cos, sin], -1))
 
 
 def _mhsa(p, x, live, n_heads):
@@ -206,11 +208,9 @@ def _mhsa(p, x, live, n_heads):
                 ).reshape(B, T, n_heads, dh)
 
     q, k, v = proj("q"), proj("k"), proj("v")
-    pos = (rel_positions(T, d, dt) @ p["w_pos"].astype(dt)).reshape(
-        2 * T - 1, n_heads, dh)
     ac = jnp.einsum("bihd,bjhd->bhij", q + p["pos_u"].astype(dt), k)
-    bd = jnp.einsum("bihd,khd->bhik", q + p["pos_v"].astype(dt), pos)
-    s = (ac + _rel_shift(bd)) / np.sqrt(dh)
+    bd = _position_scores(q + p["pos_v"].astype(dt), p["w_pos"])
+    s = (ac + bd) / np.sqrt(dh)
     s = jnp.where(live[:, None, None, :], s, MASKED)
     a = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(dt)
     o = jnp.einsum("bhij,bjhd->bihd", a, v).reshape(B, T, d)

@@ -311,3 +311,99 @@ def test_streaming_decode_matches_reference(model):
         feats[0, :L] = r.inputs["feats"]
         assert got[r.uid] == rnnt_greedy_reference(
             bundle, params, feats, np.asarray([L]), max_symbols=8)[0]
+
+
+def _shifted_position_scores(qv, w_pos):
+    """The position term as Transformer-XL forms it, kept as the oracle:
+    scores against all ``2T - 1`` relative positions ``T-1 ... -(T-1)``,
+    moved to ``(T, T)`` by the pad-and-reshape shift."""
+    B, T, H, dh = qv.shape
+    d = H * dh
+    rel = jnp.arange(T - 1, -T, -1, dtype=jnp.float32)
+    ang = rel[:, None] * 10000.0 ** (-jnp.arange(0, d, 2,
+                                                 dtype=jnp.float32) / d)
+    pos = (jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], -1) @ w_pos
+           ).reshape(2 * T - 1, H, dh)
+    x = jnp.einsum("bihd,khd->bhik", qv, pos)
+    x = jnp.pad(x, ((0, 0), (0, 0), (0, 0), (1, 0)))
+    x = x.reshape(B, H, 2 * T, T)[:, :, 1:]
+    return x.reshape(B, H, T, 2 * T - 1)[..., :T]
+
+
+def _mhsa_case(T, B, H, seed=7):
+    """Random MHSA parameters (``u`` and ``v`` too) of the smoke width at
+    ``H`` heads, an input of ``T`` frames, and live lengths with the
+    first utterance's last keys masked."""
+    from repro.models import conformer
+    r = dataclasses.replace(get_config(ARCH).rnnt, conformer_heads=H)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    p = conformer._init_block(ks[0], r)["mhsa"]
+    d = r.conformer_dim
+    p["pos_u"] = jax.random.normal(ks[1], (H, d // H))
+    p["pos_v"] = jax.random.normal(ks[2], (H, d // H))
+    x = jax.random.normal(ks[3], (B, T, d))
+    lens = jnp.asarray([max(1, T - 3)] + [T] * (B - 1))
+    return p, x, conformer._live(lens, T), H
+
+
+# The angle-difference form departs from the shift by float32 rounding
+# only: sixteen units of the last place for the sums, plus the rounding of
+# the angles i w, which grows with the position (T' units at T').
+@pytest.mark.parametrize("T,B,H", [(1, 2, 4), (2, 2, 4), (7, 2, 4),
+                                   (613, 1, 2)])
+def test_position_term_matches_shifted_oracle(T, B, H, monkeypatch):
+    """The position term by the angle-difference identity equals the
+    pad-and-reshape shift of the scores against every relative position,
+    and ``_mhsa`` with it gives the shift's outputs and gradients with
+    respect to ``w_pos``, ``pos_v`` and ``wq``, with keys masked."""
+    from repro.models import conformer
+    p, x, live, H = _mhsa_case(T, B, H)
+    d = x.shape[-1]
+    qv = jax.random.normal(jax.random.PRNGKey(T), (B, T, H, d // H))
+    ct = jax.random.normal(jax.random.PRNGKey(T + 1), x.shape)
+
+    def outputs(position_scores):
+        # a function of its own for each form, so that jit traces each
+        monkeypatch.setattr(conformer, "_position_scores", position_scores)
+
+        def run():
+            f = lambda q: conformer._mhsa(q, x, live, H)
+            g = jax.grad(lambda q: jnp.sum(f(q) * ct))(p)
+            return (position_scores(qv, p["w_pos"]), f(p),
+                    {k: g[k] for k in ("w_pos", "pos_v", "wq")})
+        return jax.jit(run)()
+
+    with jax.default_matmul_precision("highest"):
+        got = outputs(conformer._position_scores)
+        want = outputs(_shifted_position_scores)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        _close(g, w, 2.0 ** -24 * (16 + T))
+
+
+def test_block_lowering_has_no_relative_position_scores(monkeypatch):
+    """One block's forward and backward, lowered at a small T', hold no
+    array of trailing shape (T', 2T' - 1): the shift's scores against every
+    relative position do not come back.  The oracle's do show there."""
+    import re
+
+    from repro.models import conformer
+    cfg = get_config(ARCH)
+    r, T = cfg.rnnt, 9
+    p = jax.tree.map(lambda a: a[0], conformer.init_params(
+        r, jax.random.PRNGKey(0))["blocks"])
+    stats = conformer.init_stats(r)
+    x = jnp.ones((2, T, r.conformer_dim))
+    live = conformer._live(jnp.asarray([T, 5]), T)
+
+    def lowered():
+        def loss(p, x):
+            y, _ = conformer._block(p, x, live, stats["mean"][0],
+                                    stats["var"][0], r, True)
+            return jnp.sum(y * y)
+        return jax.jit(jax.grad(loss, (0, 1))).lower(p, x).as_text()
+
+    pattern = re.compile(rf"(?<![0-9]){T}x{2 * T - 1}x[a-z]")
+    assert not pattern.search(lowered())
+    monkeypatch.setattr(conformer, "_position_scores",
+                        _shifted_position_scores)
+    assert pattern.search(lowered())
